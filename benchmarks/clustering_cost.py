@@ -41,13 +41,13 @@ def _time(fn, *args, reps=3):
 
 def _madc_memory_model(n: int) -> dict:
     """Peak transient bytes (fp32): the reference materializes the (n, n, n)
-    |M_iz − M_jz| cube; the blocked kernel holds two (bn, bz) tiles, a
-    (bn, bn) accumulator, and a (sub, bn, bz) broadcast chunk — tile-sized
-    (madc_tiles picks (bn, bz) from n, capped at (128, 512))."""
+    |M_iz − M_jz| cube; the blocked kernel holds two (bn, bz) input tiles,
+    one (bn, bz) difference, the (bn, bn) accumulator and the (bn, bn) tile
+    it builds column by column — tile-sized (madc_tiles picks (bn, bz)
+    from n, capped at (128, 512))."""
     ref = 4 * n * n * n
     bn, bz = madc_tiles(n)
-    sub = min(8, bn)
-    kern = 4 * (2 * bn * bz + bn * bn + sub * bn * bz)
+    kern = 4 * (3 * bn * bz + 2 * bn * bn)
     return {"n": n, "ref_peak_bytes": ref, "kernel_tile_bytes": kern}
 
 

@@ -75,6 +75,9 @@ def main(quick: bool = False, *, m: int = 5, K: int = 50):
     reps = 5 if quick else 10
     args = json.dumps([m, K, 32, 20, 2, 10, reps])
     env = dict(os.environ)
+    # the child measures the CPU emulation of the mesh by design, and must
+    # not reach for a chip the parent process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         " --xla_force_host_platform_device_count=4")
     env["PYTHONPATH"] = os.pathsep.join(

@@ -6,6 +6,8 @@ Three terms, in seconds, per training/serving step:
   memory_s     = HBM bytes        / (chips × 819 GB/s)
   collective_s = collective bytes /  (50 GB/s per-chip ICI link)
 
+with the TPU v5e row of ``benchmarks.peaks``.
+
 METHODOLOGY NOTE (verified empirically in this repo): XLA's
 ``compiled.cost_analysis()`` counts a ``lax.scan`` (while-loop) body ONCE,
 not ×trip-count — a 61-layer scanned model reports ~1/61 of its real FLOPs.
@@ -40,9 +42,12 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from benchmarks.peaks import device_peaks                              # noqa: E402
 from repro.configs import registry, shapes as shp                      # noqa: E402
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16          # noqa: E402
 from repro.models import zoo                                          # noqa: E402
+
+# the production target of launch/mesh.py: a TPU v5e pod
+TARGET_KIND = "TPU v5 lite"
 
 DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments",
                           "dryrun")
@@ -123,6 +128,7 @@ def activation_bytes_fwd(cfg: zoo.ArchConfig, B: int, S: int) -> float:
 
 
 def analytic_terms(cfg: zoo.ArchConfig, shape: shp.InputShape, chips: int):
+    peaks = device_peaks(TARGET_KIND)
     B, S = shape.global_batch, shape.seq_len
     total, active, embed = param_census(cfg)
     if shape.kind == "train":
@@ -144,8 +150,8 @@ def analytic_terms(cfg: zoo.ArchConfig, shape: shp.InputShape, chips: int):
     return {
         "flops": flops, "bytes": bytes_, "model_flops": model_flops,
         "params_total": total, "params_active": active,
-        "compute_s": flops / (chips * PEAK_FLOPS_BF16),
-        "memory_s": bytes_ / (chips * HBM_BW),
+        "compute_s": flops / (chips * peaks["flops_bf16"]),
+        "memory_s": bytes_ / (chips * peaks["hbm_bw"]),
     }
 
 
@@ -196,7 +202,7 @@ def row_for(arch: str, shape_name: str, mesh: str = "16x16",
     terms = analytic_terms(cfg, shape, chips)
     rec = load_dryrun(arch, shape_name, mesh, suffix)
     coll_bytes = rec["collective_bytes_total"] if rec else 0.0
-    collective_s = coll_bytes / ICI_BW
+    collective_s = coll_bytes / device_peaks(TARGET_KIND)["ici_bw"]
     dom = max(("compute", terms["compute_s"]), ("memory", terms["memory_s"]),
               ("collective", collective_s), key=lambda kv: kv[1])
     return {

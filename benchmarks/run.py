@@ -77,6 +77,7 @@ from benchmarks import (async_bench, clustering_cost, docs_check,
                         obs_bench, population_bench, robustness_bench,
                         roofline, round_block, shift_bench,
                         table1_heterogeneity, table3_frameworks)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import telemetry as obs_telemetry
 
 BENCHES = {
@@ -109,6 +110,7 @@ def main(argv=None) -> int:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write every bench's derived metrics to PATH")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     names = list(BENCHES) if not args.only else args.only.split(",")
     if args.quick:

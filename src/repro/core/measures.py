@@ -52,7 +52,7 @@ def madc(M, use_kernel: bool = False, min_kernel_n: int | None = None):
     """
     if use_kernel:
         from repro.kernels.ops import madc_block, madc_crossover_n
-        cut = madc_crossover_n() if min_kernel_n is None else min_kernel_n
+        cut = madc_crossover_n(M) if min_kernel_n is None else min_kernel_n
         if M.shape[0] >= cut:
             return madc_block(M)
     n = M.shape[0]

@@ -70,9 +70,16 @@ def make_batch_solver(model: ModelSpec, *, epochs: int, batch_size: int,
 
 
 def _correct_one(model: ModelSpec):
-    """Per-client correct-prediction count (params, x, y, n_valid) -> int32."""
+    """Per-client correct-prediction count (params, x, y, n_valid) -> int32.
+
+    The forward pass runs at full f32 matmul precision. At a TPU's default
+    precision the logits are rounded through bf16, and how they round — so
+    which class wins the argmax — depends on how XLA fuses the surrounding
+    program: the standalone eval and the eval inside a scanned round block
+    counted different correct predictions for the same parameters."""
     def one(params, x, y, n_valid):
-        logits = model.apply(params, x)
+        with jax.default_matmul_precision("highest"):
+            logits = model.apply(params, x)
         pred = jnp.argmax(logits, -1)
         ok = (pred == y) & (jnp.arange(y.shape[0]) < n_valid)
         return jnp.sum(ok)

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.launch.mesh import make_fed_mesh, make_mesh
 from repro.models.modules import flatten_updates
 from repro.sharding.specs import (MP_AXIS, block_staged_pspec, cohort_pspec,
                                   data_axis_names, group_param_pspec)
@@ -62,7 +63,7 @@ def default_data_mesh():
     n = jax.device_count()
     if n <= 1:
         return None
-    return jax.make_mesh((n,), ("data",))
+    return make_mesh((n,), ("data",))
 
 
 def default_fed_mesh(model_axis: int | None = None):
@@ -83,7 +84,7 @@ def default_fed_mesh(model_axis: int | None = None):
     if n % model_axis:
         raise ValueError(f"model_axis={model_axis} does not divide the "
                          f"{n} visible devices")
-    return jax.make_mesh((n // model_axis, model_axis), ("data", MP_AXIS))
+    return make_fed_mesh(n // model_axis, model_axis)
 
 
 def mesh_data_shards(mesh) -> int:

@@ -47,7 +47,7 @@ def _kernel(dw_ref, v_ref, vnorm_ref, out_ref, acc_ref, nrm_ref, *, nd: int):
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_d", "interpret"))
 def edc_cosine(dW, V, *, block_n: int = 128, block_d: int = 512,
-               interpret: bool = True):
+               interpret: bool):
     """dW: (n, d), V: (d, m) -> (n, m) cosine similarities (fp32).
 
     Wrapper pads n to block_n, d to block_d and m to the 128-lane tile.

@@ -14,9 +14,10 @@ the accumulation instead of materializing and re-masking the full cube.
 Peak live memory is O(bn·bz) per step — independent of n — and M is read
 from HBM once per (i, j) block row-pair.
 
-The intra-tile broadcast is chunked over ``sub_n`` rows of the i block so
-the (sub_n, bn, bz) temporary stays a few hundred KB regardless of the
-128-aligned block shapes.
+Inside a step the tile is built one column at a time: row b of the j block
+is broadcast against the whole i block as a (bn, bz) difference and
+reduced over the lane (z) axis. Everything stays a 2-D tile, the shape
+Mosaic lowers; a 3-D (bn, bn, bz) broadcast does not compile for the chip.
 """
 from __future__ import annotations
 
@@ -33,8 +34,8 @@ def madc_tiles(n: int) -> tuple:
 
     block_n (sublane) rounds n up to the fp32 tile's 8-row granule, capped
     at 128; block_z (lane) rounds up to the mandatory 128-lane granule,
-    capped at 512 (two (bn, bz) input tiles + the (sub, bn, bz) broadcast
-    chunk stay well under VMEM at the cap). Small n therefore stops padding
+    capped at 512 (two (bn, bz) input tiles + the (bn, bz) difference stay
+    well under VMEM at the cap). Small n therefore stops padding
     to a full 128x128 tile — at n=32 the kernel does 16x less tile work
     than the old fixed blocks.
     """
@@ -44,7 +45,7 @@ def madc_tiles(n: int) -> tuple:
 
 
 def _kernel(mi_ref, mj_ref, out_ref, acc_ref, *, nz: int, n: int,
-            block_n: int, block_z: int, sub_n: int):
+            block_n: int, block_z: int):
     i, j, z = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(z == 0)
@@ -52,21 +53,26 @@ def _kernel(mi_ref, mj_ref, out_ref, acc_ref, *, nz: int, n: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     mi = mi_ref[...].astype(jnp.float32)          # (bn, bz) rows of i block
-    mj = mj_ref[...].astype(jnp.float32)          # (bn, bz) rows of j block
+    shape = (block_n, block_z)
+    z_idx = jax.lax.broadcasted_iota(jnp.int32, shape, 1) + z * block_z
+    i_idx = jax.lax.broadcasted_iota(jnp.int32, shape, 0) + i * block_n
+    # z exclusion of the i side (self-similarity bias, eq. 7) + padding
+    # columns; the j side is one scalar per column below
+    excl_i = (z_idx == i_idx) | (z_idx >= n)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (block_n, block_n), 1)
 
-    # chunk the (bn, bn, bz) broadcast over sub_n rows of the i block to
-    # bound the live temporary at sub_n * bn * bz floats
-    for a0 in range(0, block_n, sub_n):
-        a1 = min(a0 + sub_n, block_n)
-        diff = jnp.abs(mi[a0:a1, None, :] - mj[None, :, :])  # (sub, bn, bz)
-        shape = diff.shape
-        z_idx = jax.lax.broadcasted_iota(jnp.int32, shape, 2) + z * block_z
-        i_idx = (jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-                 + i * block_n + a0)
-        j_idx = jax.lax.broadcasted_iota(jnp.int32, shape, 1) + j * block_n
-        # z exclusion (self-similarity bias, eq. 7) + padding columns
-        excl = (z_idx == i_idx) | (z_idx == j_idx) | (z_idx >= n)
-        acc_ref[a0:a1, :] += jnp.sum(jnp.where(excl, 0.0, diff), axis=-1)
+    # one column of the (bn, bn) tile per j row: a (bn, bz) difference
+    # against the broadcast row, reduced over the lane (z) axis — only 2-D
+    # tiles and lane reductions, which Mosaic lowers
+    def column(b, tile):
+        mj = mj_ref[pl.ds(b, 1), :].astype(jnp.float32)      # (1, bz)
+        excl = excl_i | (z_idx == j * block_n + b)
+        diff = jnp.where(excl, 0.0, jnp.abs(mi - mj))
+        col = jnp.sum(diff, axis=1, keepdims=True)            # (bn, 1)
+        return jnp.where(lane == b, col, tile)
+
+    acc_ref[...] += jax.lax.fori_loop(
+        0, block_n, column, jnp.zeros((block_n, block_n), jnp.float32))
 
     @pl.when(z == nz - 1)
     def _finish():
@@ -76,7 +82,7 @@ def _kernel(mi_ref, mj_ref, out_ref, acc_ref, *, nz: int, n: int,
 @functools.partial(jax.jit,
                    static_argnames=("block_n", "block_z", "interpret"))
 def madc_block(M, *, block_n: int | None = None, block_z: int | None = None,
-               interpret: bool = True):
+               interpret: bool):
     """M: (n, n) cosine similarities -> (n, n) MADC dissimilarities (fp32).
 
     Block shapes default to ``madc_tiles(n)`` — sized from n, not fixed
@@ -95,7 +101,7 @@ def madc_block(M, *, block_n: int | None = None, block_z: int | None = None,
     grid = (rn // block_n, rn // block_n, nz)
     out = pl.pallas_call(
         functools.partial(_kernel, nz=nz, n=n, block_n=block_n,
-                          block_z=block_z, sub_n=min(8, block_n)),
+                          block_z=block_z),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, block_z), lambda i, j, z: (i, z)),

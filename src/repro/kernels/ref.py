@@ -10,11 +10,12 @@ _EPS = 1e-12
 def cosine_block_ref(dW, V):
     """E[i, j] = <ΔW_i, V_:,j> / (||ΔW_i|| ||V_:,j||).
 
-    dW: (n, d); V: (d, m) -> (n, m) float32.
+    dW: (n, d); V: (d, m) -> (n, m) float32. The products are full f32 on
+    every backend (a TPU's default matmul precision rounds to bf16).
     """
     dW32 = dW.astype(jnp.float32)
     V32 = V.astype(jnp.float32)
-    dots = dW32 @ V32
+    dots = jnp.matmul(dW32, V32, precision=jax.lax.Precision.HIGHEST)
     rn = jnp.linalg.norm(dW32, axis=1, keepdims=True)
     cn = jnp.linalg.norm(V32, axis=0, keepdims=True)
     return dots / jnp.maximum(rn * cn, _EPS)

@@ -46,7 +46,7 @@ def _kernel(x_ref, a_ref, b_ref, c_ref, y_ref, st_ref, *, q: int):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_intra_chunk(X, A_cs, B, C, *, interpret: bool = True):
+def ssd_intra_chunk(X, A_cs, B, C, *, interpret: bool):
     """X: (BH, NC, Q, P); A_cs: (BH, NC, Q) inclusive-cumsum log decays;
     B, C: (BH, NC, Q, N). Returns (Y_diag (BH,NC,Q,P) fp32,
     states (BH,NC,N,P) fp32)."""

@@ -72,7 +72,7 @@ def _kernel(q_ref, k_ref, v_ref, out_ref, m_ref, l_ref, acc_ref, *,
     "window", "causal", "block_q", "block_k", "interpret"))
 def swa_attention(q, k, v, *, window: int | None = None, causal: bool = True,
                   block_q: int = 128, block_k: int = 128,
-                  interpret: bool = True):
+                  interpret: bool):
     """q: (B, Sq, H, hd); k, v: (B, Sk, H, hd) -> (B, Sq, H, hd).
 
     Query i sits at absolute position i + (Sk - Sq) (decode-tail alignment,

@@ -49,6 +49,8 @@ import os
 import time
 from dataclasses import dataclass, field
 
+import jax
+
 from repro.fed import leases as leases_lib
 from repro.launch import transport as transport_lib
 from repro.launch import worker as worker_lib
@@ -66,7 +68,8 @@ class FleetConfig:
     transport           "inproc" (thread workers, bit-identity mode) or
                         "proc" (spawned processes, real fault domains —
                         requires ``worker_spec``; per-round pinned path
-                        only).
+                        only; refused on a TPU backend, where the chip
+                        belongs to the coordinator's process).
     heartbeat_interval  worker beat period (seconds).
     heartbeat_miss      beats missed before a worker is declared dead.
     lease_timeout /     the fleet job lease's ``fed.leases.RetryPolicy``:
@@ -140,6 +143,13 @@ class Coordinator:
     # -- setup ----------------------------------------------------------
     def _validate_proc(self, trainer):
         cfg = trainer.cfg
+        if jax.default_backend() == "tpu":
+            # a chip belongs to one process: this one holds it, so spawned
+            # workers would fail or hang on it (or quietly use the CPU)
+            raise ValueError("proc transport cannot run on a TPU backend: "
+                             "its spawned workers would need the chip this "
+                             "process holds — use transport='inproc', whose "
+                             "thread workers share it")
         if self.fleet.worker_spec is None:
             raise ValueError("proc transport needs FleetConfig.worker_spec "
                              "(the worker-side trainer replica recipe)")

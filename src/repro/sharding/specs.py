@@ -15,7 +15,7 @@ Two families of specs live here:
 
 >>> from repro.sharding.specs import cohort_pspec, group_param_pspec
 >>> cohort_pspec(2, data_axes=("data",))          # (K, max_n) client batch
-PartitionSpec(('data',), None)
+PartitionSpec('data', None)
 >>> group_param_pspec((3, 16, 10), model_size=2)  # m-stacked (m, d, C) leaf
 PartitionSpec(None, 'model', None)
 >>> group_param_pspec((3, 16, 10), model_size=1)  # model axis 1: replicate
@@ -197,10 +197,18 @@ def data_axis_names(mesh) -> tuple:
     return named or tuple(mesh.axis_names)
 
 
+def _data_entry(data_axes):
+    """One PartitionSpec entry over the data axes: the bare axis name for a
+    single axis (the form ``PartitionSpec`` normalizes a 1-tuple to), the
+    tuple for several."""
+    data_axes = tuple(data_axes)
+    return data_axes[0] if len(data_axes) == 1 else data_axes
+
+
 def cohort_pspec(ndim: int, data_axes=("data",)) -> P:
     """Spec for one K-leading cohort leaf (X/Y/n/keys/assignment state):
     client axis sharded over the data axes, everything else replicated."""
-    return P(tuple(data_axes), *([None] * (ndim - 1)))
+    return P(_data_entry(data_axes), *([None] * (ndim - 1)))
 
 
 def block_staged_pspec(ndim: int, data_axes=("data",)) -> P:
@@ -212,9 +220,11 @@ def block_staged_pspec(ndim: int, data_axes=("data",)) -> P:
 
     >>> from repro.sharding.specs import block_staged_pspec
     >>> block_staged_pspec(2, data_axes=("data",))   # (B, K) cohort ids
-    PartitionSpec(None, ('data',))
+    PartitionSpec(None, 'data')
+    >>> block_staged_pspec(2, data_axes=("pod", "data"))
+    PartitionSpec(None, ('pod', 'data'))
     """
-    return P(None, tuple(data_axes), *([None] * (ndim - 2)))
+    return P(None, _data_entry(data_axes), *([None] * (ndim - 2)))
 
 
 def group_param_pspec(shape: tuple, model_size: int,

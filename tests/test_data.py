@@ -1,7 +1,7 @@
 """Data pipeline: generators, partitioners, padding containers."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.data import generators as gen
 from repro.data.federated import power_law_sizes

@@ -392,13 +392,26 @@ class TestChaosRecovery:
         coord = Coordinator(tr, FleetConfig(n_workers=2, lease_timeout=0.4,
                                             **FAST))
         real = coord._table["round"]
-        stalled = threading.Event()
+        # compile the executor up front (state-free call): the requeued job
+        # must answer well inside its own 0.4 s lease
+        x, y, n = tr._client_batch(np.arange(8))
+        jax.block_until_ready(real(
+            jax.tree_util.tree_map(lambda p: p[None], tr.params),
+            np.zeros(8, np.int32), x, y, n,
+            jax.random.split(jax.random.PRNGKey(0), 8)))
+        calls = []
+        requeued_done = threading.Event()
 
         def stall_once(*args):
-            if not stalled.is_set():
-                stalled.set()
-                time.sleep(1.2)             # > lease_timeout: expires
-            return real(*args)
+            calls.append(1)
+            if len(calls) == 1:
+                # hold the first job until its lease expired and the
+                # requeued copy was answered by the other worker
+                assert requeued_done.wait(timeout=120.0)
+                return real(*args)
+            out = real(*args)
+            requeued_done.set()
+            return out
 
         coord._table["round"] = stall_once
         h = coord.run()
@@ -546,6 +559,22 @@ class TestProcFleet:
             Coordinator(asy,
                         FleetConfig(transport="proc", worker_spec=spec))
         asy.close()
+
+    def test_proc_mode_refuses_a_tpu_backend(self, small_model, small_data,
+                                             monkeypatch):
+        # one process per chip: spawned workers cannot share the chip the
+        # coordinator's process holds, so the refusal comes before any spawn
+        import repro.launch.coordinator as coord_mod
+        tr = _fresh(FedAvgTrainer, small_model, small_data, False)
+        spawned = []
+        monkeypatch.setattr(coord_mod.jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(coord_mod.ProcTransport, "add_worker",
+                            lambda *a, **k: spawned.append(a))
+        spec = WorkerSpec("repro.launch.worker:synthetic_builder", PROC_KW)
+        with pytest.raises(ValueError, match="transport='inproc'"):
+            Coordinator(tr, FleetConfig(transport="proc", worker_spec=spec))
+        assert spawned == []
+        tr.close()
 
     def test_bad_builder_spec_is_rejected(self):
         from repro.launch.worker import resolve_builder

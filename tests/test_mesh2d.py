@@ -131,7 +131,9 @@ class TestAsyncStateScatter:
 class TestFedRoundSpecs:
     def test_cohort_pspec_shards_client_axis_only(self):
         spec = cohort_pspec(3, data_axes=("data",))
-        assert tuple(spec) == (("data",), None, None)
+        assert tuple(spec) == ("data", None, None)
+        spec = cohort_pspec(2, data_axes=("pod", "data"))
+        assert tuple(spec) == (("pod", "data"), None)
 
     def test_group_param_pspec_picks_largest_divisible_dim(self):
         # (m, d, C): d=16 divides 2, C=10 does not -> shard d over "model"

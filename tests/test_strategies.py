@@ -8,8 +8,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
-from _hypothesis_compat import given, settings, st
 from repro.fed import client as client_lib
 from repro.fed import rounds, server as server_lib, strategies
 from repro.fed.engine import FedConfig
