@@ -1,0 +1,83 @@
+"""The numbers that decide ``correct``: the program's check rounds against
+the reference's.
+
+A check round's reading is a dict with the group models ``groups``
+(m-stacked), the auxiliary global model ``glob``, the round's mean local
+loss ``loss`` and its correct test predictions ``correct``. ``start`` is
+the state both sides started from. The numbers:
+
+  loss_gap    max over the check rounds of |loss - ref| / |ref|
+  update_gap  the first round's change of every leaf (each group's and the
+              global model's), by its norm: the worst leaf's
+              |norm - ref norm| / max(ref norm, median ref norm)
+  change_gap  the same for the change over all the check rounds
+  acc_gap     max over the check rounds that evaluated of
+              |correct - ref| / the test samples evaluated
+
+A leaf whose reference change is under a thousandth of the median leaf's
+(a group that no cohort client joined) moves by round-off alone, and is
+left out of update_gap and change_gap.
+"""
+from __future__ import annotations
+
+import numpy as np
+
+from bench.reference import LEAVES
+
+NEGLIGIBLE = 1e-3
+
+
+def leaf_norms(after: dict, before: dict) -> dict:
+    """Norm of every leaf's change, per group and for the global model."""
+    out = {}
+    m = next(iter(after["groups"].values())).shape[0]
+    for k in LEAVES:
+        for j in range(m):
+            d = (np.asarray(after["groups"][k][j], np.float64)
+                 - np.asarray(before["groups"][k][j], np.float64))
+            out[f"group{j}.{k}"] = float(np.linalg.norm(d))
+        d = (np.asarray(after["glob"][k], np.float64)
+             - np.asarray(before["glob"][k], np.float64))
+        out[f"global.{k}"] = float(np.linalg.norm(d))
+    return out
+
+
+def norm_gap(prog: dict, ref: dict) -> float:
+    """Worst leaf's gap between the program's and the reference's change
+    norm, against the larger of its reference norm and the median's."""
+    med = float(np.median(list(ref.values())))
+    gaps = [abs(prog[k] - r) / max(r, med) for k, r in ref.items()
+            if r >= NEGLIGIBLE * med]
+    return max(gaps) if gaps else 0.0
+
+
+def numbers(start: dict, prog: list, ref: list) -> dict:
+    """The compared numbers of one run (see the module docstring);
+    acc_gap only where a check round evaluated."""
+    out = {
+        "loss_gap": float(max(abs(p["loss"] - r["loss"]) / abs(r["loss"])
+                              for p, r in zip(prog, ref))),
+        "update_gap": norm_gap(leaf_norms(prog[0], start),
+                               leaf_norms(ref[0], start)),
+        "change_gap": norm_gap(leaf_norms(prog[-1], start),
+                               leaf_norms(ref[-1], start)),
+    }
+    gaps = [g for r in ref for g in r["assign_gaps"]]
+    if gaps:
+        out["assign_gap"] = max(gaps)
+    evals = [(p, r) for p, r in zip(prog, ref) if p["correct"] is not None]
+    if evals:
+        out["acc_gap"] = max(abs(p["correct"] - r["correct"])
+                             / max(p["n_test"], 1) for p, r in evals)
+    return out
+
+
+def verdict(nums: dict, limits: dict) -> tuple:
+    """-> (correct, checks): every limited number within its limit, and
+    each number beside its limit. A limit of None marks a number that is
+    shown and does not decide."""
+    checks = {k: {"value": v, "limit": limits[k]} for k, v in nums.items()}
+    ok = all(np.isfinite(v) for v in nums.values()) and all(
+        c["value"] <= c["limit"] for c in checks.values()
+        if c["limit"] is not None)
+    return bool(ok), checks
