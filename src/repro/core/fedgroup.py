@@ -195,24 +195,27 @@ class FedGroupTrainer(GroupedTrainer):
         if len(cold_idx) == 0:
             return
         self.obs.registry.inc("rounds.cold_started", len(cold_idx))
-        if cfg.rac:                                            # ablation
+        with self.obs.span("cold-start", n=int(len(cold_idx))):
+            if cfg.rac:                                        # ablation
+                self._adopt_membership(cold_idx,
+                                       self.rng.integers(0, self.m,
+                                                         len(cold_idx)))
+                return
+            x, y, n = self._client_batch(cold_idx)
+            self.key, sk = jax.random.split(self.key)
+            keys = jax.random.split(sk, len(cold_idx))
+            deltas, _ = self.pretrain_solver(self.params, x, y, n, keys)
+            dpre = jax.vmap(flatten_updates)(deltas)           # (c, d_w)
+            if self.population is not None or self._shift_enabled():
+                # cache the pre-training directions (persistent state
+                # table when streaming, trainer-owned rows when pinned):
+                # newcomer analytics, re-clustering and the shift
+                # detector reuse them
+                self._set_dirs(cold_idx, np.asarray(dpre))
+            sim = measures.cosine_similarity_matrix(dpre, self.group_delta)
+            dis = (-sim + 1.0) / 2.0                           # (c, m)
             self._adopt_membership(cold_idx,
-                                   self.rng.integers(0, self.m,
-                                                     len(cold_idx)))
-            return
-        x, y, n = self._client_batch(cold_idx)
-        self.key, sk = jax.random.split(self.key)
-        keys = jax.random.split(sk, len(cold_idx))
-        deltas, _ = self.pretrain_solver(self.params, x, y, n, keys)
-        dpre = jax.vmap(flatten_updates)(deltas)               # (c, d_w)
-        if self.population is not None or self._shift_enabled():
-            # cache the pre-training directions (persistent state table
-            # when streaming, trainer-owned rows when pinned): newcomer
-            # analytics, re-clustering and the shift detector reuse them
-            self._set_dirs(cold_idx, np.asarray(dpre))
-        sim = measures.cosine_similarity_matrix(dpre, self.group_delta)
-        dis = (-sim + 1.0) / 2.0                               # (c, m)
-        self._adopt_membership(cold_idx, np.asarray(jnp.argmin(dis, axis=1)))
+                                   np.asarray(jnp.argmin(dis, axis=1)))
 
     # ------------------------------------------------------------------
     # Shift detection + migration (FlexCFL-style, FedConfig.shift_threshold)
@@ -384,38 +387,33 @@ class FedGroupTrainer(GroupedTrainer):
     # Round (Algorithm 2) — one fused dispatch over all groups
     # ------------------------------------------------------------------
     def round(self, t: int, idx=None) -> RoundMetrics:
-        if not self.cold_started:
-            self.group_cold_start()
+        with self.obs.span("round", t=t):
+            if not self.cold_started:
+                self.group_cold_start()
 
-        if idx is None:
-            idx = self._select()
-        idx = np.asarray(idx)
-        self._maybe_shift(idx)
-        cold = idx[self.membership[idx] < 0]
-        self.last_cold = len(cold)
-        # cold start: 1 global model down + 1 pretrain update up per newcomer
-        self.comm_params += 2 * len(cold) * self.model_size
-        self.client_cold_start(cold)
-        # per-round: 1 group model down + 1 update up per client
-        self.comm_params += 2 * len(idx) * self.model_size
+            if idx is None:
+                idx = self._select()
+            idx = np.asarray(idx)
+            self._maybe_shift(idx)
+            cold = idx[self.membership[idx] < 0]
+            self.last_cold = len(cold)
+            # cold start: 1 global model down + 1 pretrain update up per
+            # newcomer
+            self.comm_params += 2 * len(cold) * self.model_size
+            self.client_cold_start(cold)
+            # per-round: 1 group model down + 1 update up per client
+            self.comm_params += 2 * len(idx) * self.model_size
 
-        x, y, n = self._client_batch(idx)
-        self.key, sk = jax.random.split(self.key)
-        keys = jax.random.split(sk, len(idx))
-        out = self._round_executor()(
-            self.group_params, jnp.asarray(self.membership[idx], jnp.int32),
-            x, y, n, keys)
-        self.group_params = out.group_params
-        self.group_delta = out.group_delta_flat
-        # auxiliary global model: unweighted average of group models
-        self.params = out.global_params
-
-        acc = self._round_eval(t)
-        self._fold_alive = len(idx)
-        m = RoundMetrics(t, acc, float(out.mean_loss), float(out.discrepancy),
-                         int(out.n_quarantined))
-        self.history.add(m)
-        return m
+            x, y, n, keys = self._stage_cohort(idx)
+            out = self._round_executor()(
+                self.group_params,
+                jnp.asarray(self.membership[idx], jnp.int32), x, y, n, keys)
+            with self.obs.span("fold"):
+                self.group_params = out.group_params
+                self.group_delta = out.group_delta_flat
+                # auxiliary global model: unweighted average of group models
+                self.params = out.global_params
+                return self._fold_round(t, out, idx)
 
 
 class FedGrouProxTrainer(FedGroupTrainer):

@@ -109,6 +109,7 @@ def grouped_eval_correct(model: ModelSpec):
     """
     one = _correct_one(model)
 
+    @jax.named_scope("grouped_eval")
     def fn(group_params, membership, Xt, Yt, nt):
         membership = membership.astype(jnp.int32)
         valid = membership >= 0

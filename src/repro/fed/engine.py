@@ -79,6 +79,14 @@ from repro.obs import telemetry as obs_lib
 _AsyncLease = leases_lib.Lease
 
 
+@jax.jit
+def gather_cohort(x, y, n, sel):
+    """A cohort's rows of the pinned ``(x, y, n)`` stacks as ONE program,
+    named ``jit_gather_cohort`` in a profile (three eager gathers would be
+    three programs, each behind its own index checks)."""
+    return x[sel], y[sel], n[sel]
+
+
 @dataclass
 class FedConfig:
     n_rounds: int = 50
@@ -452,12 +460,17 @@ class FedAvgTrainer:
         keys = jnp.asarray(np.stack([s[1] for s in staged]))
         alive = jnp.asarray(np.stack([s[2] for s in staged]))
         do_eval = np.asarray([s[3] for s in staged], bool)
+        for s in staged:
+            self._count_steps(self._solver_steps(s[0], s[2]))
         carry, ys = self._block_executor()(
             self._carry_in(), self._train_stack, self._test_stack,
             idx, keys, alive, jnp.asarray(do_eval))
-        self._carry_out(carry)
-        # ONE device fetch for the whole block's stacked metrics
-        mean_loss, disc, correct, total, n_quar = (np.asarray(v) for v in ys)
+        # ONE device fetch for the whole block's stacked metrics (and the
+        # carried membership, which the grouped trainers read back)
+        with self.obs.span("sync", t=t0):
+            self._carry_out(carry)
+            mean_loss, disc, correct, total, n_quar = (np.asarray(v)
+                                                       for v in ys)
         for b in range(len(staged)):
             acc = (int(correct[b]) / max(int(total[b]), 1)
                    if do_eval[b] else float("nan"))
@@ -467,19 +480,24 @@ class FedAvgTrainer:
 
     # -- helpers -----------------------------------------------------------
     def _select(self):
-        if self.population is not None:
-            return self.population.next_cohort().idx
-        idx = self.select_rng.choice(self.n_clients,
-                                     min(self.cfg.clients_per_round,
-                                         self.n_clients), replace=False)
-        if self.cfg.dropout_rate > 0.0:
-            # stragglers drop out before completing the round (the server
-            # aggregates whoever finished within the time budget, Alg. 1)
-            alive = self.select_rng.random(len(idx)) >= self.cfg.dropout_rate
-            if not alive.any():
-                alive[self.select_rng.integers(len(idx))] = True
-            idx = idx[alive]
-        return idx
+        """The cohort draw (a ``select`` span; streamed, it includes the
+        wait for the prefetched cohort)."""
+        with self.obs.span("select"):
+            if self.population is not None:
+                return self.population.next_cohort().idx
+            idx = self.select_rng.choice(self.n_clients,
+                                         min(self.cfg.clients_per_round,
+                                             self.n_clients), replace=False)
+            if self.cfg.dropout_rate > 0.0:
+                # stragglers drop out before completing the round (the
+                # server aggregates whoever finished within the time
+                # budget, Alg. 1)
+                alive = (self.select_rng.random(len(idx))
+                         >= self.cfg.dropout_rate)
+                if not alive.any():
+                    alive[self.select_rng.integers(len(idx))] = True
+                idx = idx[alive]
+            return idx
 
     def _client_batch(self, idx):
         if self.population is not None:
@@ -487,8 +505,51 @@ class FedAvgTrainer:
             # them, e.g. the cold-start subset); store gather otherwise
             return self.population.device_batch(idx)
         sel = jnp.asarray(np.asarray(idx, np.int32))
-        x, y, n = self._train_stack
-        return x[sel], y[sel], n[sel]
+        return gather_cohort(*self._train_stack, sel)
+
+    def _stage_cohort(self, idx):
+        """The dispatches that stage a cohort for the per-round executor:
+        its batch and its solver keys (a ``stage`` span). Counts the solver
+        steps the round's dispatch runs."""
+        with self.obs.span("stage"):
+            x, y, n = self._client_batch(idx)
+            self.key, sk = jax.random.split(self.key)
+            keys = jax.random.split(sk, len(idx))
+        self._count_steps(self._solver_steps(idx))
+        return x, y, n, keys
+
+    def _solver_steps(self, idx, alive=None) -> tuple:
+        """(steps run, steps live) of one dispatched cohort, from the
+        host-side client sizes, never a device read: every lane runs the
+        solver's E * ceil(max_n / B) steps; an alive client's first
+        E * ceil(n_i / B) of them are live, the rest masked."""
+        e, b = self.cfg.local_epochs, self.cfg.batch_size
+        sizes = (self.population.store if self.population is not None
+                 else self.data).n_train
+        n = np.maximum(np.asarray(sizes)[np.asarray(idx)].astype(np.int64),
+                       1)
+        live = e * ((n + b - 1) // b)
+        if alive is not None:
+            live = live * (np.asarray(alive) > 0)
+        run = n.size * e * ((self._max_samples + b - 1) // b)
+        return int(run), int(live.sum())
+
+    def _count_steps(self, steps):
+        run, live = steps
+        self.obs.registry.inc("solver.steps_run", run)
+        self.obs.registry.inc("solver.steps_live", live)
+
+    def _fold_round(self, t: int, out, idx) -> RoundMetrics:
+        """The rest of the per-round path's fold once the outputs are
+        adopted: the eval, the host reads of the round's scalars (a
+        ``sync`` span) and ``History.add``."""
+        acc = self._round_eval(t)
+        self._fold_alive = len(idx)
+        with self.obs.span("sync"):
+            m = RoundMetrics(t, acc, float(out.mean_loss),
+                             float(out.discrepancy), int(out.n_quarantined))
+        self.history.add(m)
+        return m
 
     def _solve(self, params, idx):
         x, y, n = self._client_batch(idx)
@@ -508,8 +569,9 @@ class FedAvgTrainer:
         correct = total = 0
         for block, x, y, n in pop.eval_batches(idx):
             c = self.eval_fn(params, x, y, n)
-            correct += int(np.sum(np.asarray(c)))
-            total += int(np.sum(np.asarray(n)))
+            with self.obs.span("sync"):
+                correct += int(np.sum(np.asarray(c)))
+                total += int(np.sum(np.asarray(n)))
         return correct, total
 
     def _should_eval(self, t: int) -> bool:
@@ -530,7 +592,9 @@ class FedAvgTrainer:
         xt, yt, nt = self._test_stack
         c, tot = self._grouped_eval_fn()(group_params, membership,
                                          xt, yt, nt)
-        return int(c) / max(int(tot), 1)
+        with self.obs.span("sync"):
+            c, tot = int(c), int(tot)
+        return c / max(tot, 1)
 
     def _round_eval(self, t: int) -> float:
         """The per-round training loop's evaluation hook (NaN off-cadence).
@@ -569,23 +633,18 @@ class FedAvgTrainer:
 
     # -- main loop ---------------------------------------------------------
     def round(self, t: int, idx=None) -> RoundMetrics:
-        if idx is None:
-            idx = self._select()
-        x, y, n = self._client_batch(idx)
-        self.key, sk = jax.random.split(self.key)
-        keys = jax.random.split(sk, len(idx))
-        # downlink: 1 model per client; uplink: 1 update per client
-        self.comm_params += 2 * len(idx) * self.model_size
-        out = self._round_executor()(
-            jax.tree_util.tree_map(lambda p: p[None], self.params),
-            jnp.zeros(len(idx), jnp.int32), x, y, n, keys)
-        self.params = out.global_params
-        acc = self._round_eval(t)
-        self._fold_alive = len(idx)
-        m = RoundMetrics(t, acc, float(out.mean_loss), float(out.discrepancy),
-                         int(out.n_quarantined))
-        self.history.add(m)
-        return m
+        with self.obs.span("round", t=t):
+            if idx is None:
+                idx = self._select()
+            x, y, n, keys = self._stage_cohort(idx)
+            # downlink: 1 model per client; uplink: 1 update per client
+            self.comm_params += 2 * len(idx) * self.model_size
+            out = self._round_executor()(
+                jax.tree_util.tree_map(lambda p: p[None], self.params),
+                jnp.zeros(len(idx), jnp.int32), x, y, n, keys)
+            with self.obs.span("fold"):
+                self.params = out.global_params
+                return self._fold_round(t, out, idx)
 
     def run(self, n_rounds=None) -> History:
         """The block-scheduling loop. With ``block_size > 1`` on the pinned
@@ -715,7 +774,8 @@ class FedAvgTrainer:
         the same host sequence (and the same rng draw order) as the
         synchronous paths. Returns ``(cold_ids, staged_inputs)``; the
         staged inputs are kept device-resident so an expired lease can
-        re-dispatch them against the then-current state."""
+        re-dispatch them against the then-current state, and end with the
+        cohort's (steps run, steps live), counted at every dispatch."""
         with self.obs.span("stage", t=t):
             self._async_host_pre()
             idx = self._select()
@@ -723,13 +783,15 @@ class FedAvgTrainer:
             if self.population is None:
                 idx_p, keys, alive, _ = self._stage_round(t, idx)
                 return cold, (jnp.asarray(idx_p), jnp.asarray(keys),
-                              jnp.asarray(alive))
+                              jnp.asarray(alive),
+                              self._solver_steps(idx_p, alive))
             x, y, n = self._client_batch(idx)
             self.key, sk = jax.random.split(self.key)
             keys = jax.random.split(sk, len(idx))
             self._stage_comm(len(idx))
             return cold, (np.asarray(idx), x, y, n, keys,
-                          self._async_stream_arg(idx))
+                          self._async_stream_arg(idx),
+                          self._solver_steps(idx))
 
     def _lease_ready(self, leaves) -> bool:
         """True when every device buffer of a lease's result is computed
@@ -792,8 +854,9 @@ class FedAvgTrainer:
         t_fold = t0                  # rounds folded so far
 
         def dispatch(staged, attempts):
+            self._count_steps(staged[-1])
             if pinned:
-                idx_d, keys_d, alive_d = staged
+                idx_d, keys_d, alive_d, _ = staged
                 result, mets = exec_(carry, self._train_stack,
                                      idx_d, keys_d, alive_d)
             else:
@@ -851,12 +914,13 @@ class FedAvgTrainer:
                     self._last_weights = [float(v)
                                           for v in np.asarray(w).ravel()]
                 if pinned:
-                    idx_d, _, alive_d = lease.staged
+                    idx_d, _, alive_d, _ = lease.staged
                     carry = fold(carry, lease.result, idx_d, alive_d,
                                  jnp.asarray(w))
                     self._carry_refs(carry)
-                    mean_loss, disc, n_quar, mem = (np.asarray(v)
-                                                    for v in lease.metrics)
+                    with self.obs.span("sync"):
+                        mean_loss, disc, n_quar, mem = (
+                            np.asarray(v) for v in lease.metrics)
                     alive_h = np.asarray(alive_d)
                     self._fold_alive = int(alive_h.sum())
                     occupied = np.unique(mem[alive_h > 0])
@@ -874,15 +938,18 @@ class FedAvgTrainer:
                                         jnp.asarray(w))
                     self._async_adopt(out, lease.staged[0], groups, glob)
                     self._fold_alive = int(len(lease.staged[0]))
-                    occupied = np.unique(np.asarray(out.membership))
+                    with self.obs.span("sync"):
+                        occupied = np.unique(np.asarray(out.membership))
                     mean_loss, disc, n_quar = (out.mean_loss,
                                                out.discrepancy,
                                                out.n_quarantined)
                     acc = self._round_eval(t)
                 ver[occupied] += 1
                 st["folds"] += 1
-                self.history.add(RoundMetrics(t, acc, float(mean_loss),
-                                              float(disc), int(n_quar)))
+                with self.obs.span("sync"):
+                    m = RoundMetrics(t, acc, float(mean_loss), float(disc),
+                                     int(n_quar))
+                self.history.add(m)
             t_fold += 1
 
         def harvest():

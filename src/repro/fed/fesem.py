@@ -122,41 +122,42 @@ class FeSEMTrainer(GroupedTrainer):
             idx, np.asarray(out.assign_state["local_flat"]))
 
     def round(self, t: int, idx=None) -> RoundMetrics:
-        if idx is None:
-            idx = self._select()
-        # FeSEM: server-side E-step, then 1 center down + 1 model up
-        self.comm_params += 2 * len(idx) * self.model_size
-        x, y, n = self._client_batch(idx)
-        self.key, sk = jax.random.split(self.key)
-        keys = jax.random.split(sk, len(idx))
-        if self.population is not None:
-            # state-table gather: cohort rows with cohort-local ids — the
-            # executor program is byte-identical to the pinned one, the
-            # E-step gather/M-step scatter just act on (K, d_w) instead of
-            # the full (N, d_w). The population gather drains the async
-            # writer first, so last round's per-shard scatters are visible.
-            rows = jnp.asarray(self.population.gather_local_flat(idx))
-            state = {"local_flat": rows,
-                     "idx": jnp.arange(len(idx), dtype=jnp.int32)}
-        else:
-            state = {"local_flat": self.local_flat,
-                     "idx": jnp.asarray(np.asarray(idx, np.int32))}
-        out = self._round_executor()(self.group_params, state, x, y, n, keys)
-        self.group_params = out.group_params
-        if self.population is not None:
-            # async per-shard write-back: overlaps evaluation + the next
-            # cohort's H2D; the next gather_local_flat drains it first
-            self.population.scatter_local_flat(
-                idx, np.asarray(out.assign_state["local_flat"]))
-        else:
-            self.local_flat = out.assign_state["local_flat"]
-        self._adopt_membership(idx, out.membership)
-        acc = self._round_eval(t)
-        self._fold_alive = len(idx)
-        m = RoundMetrics(t, acc, float(out.mean_loss), float(out.discrepancy),
-                         int(out.n_quarantined))
-        self.history.add(m)
-        return m
+        with self.obs.span("round", t=t):
+            if idx is None:
+                idx = self._select()
+            # FeSEM: server-side E-step, then 1 center down + 1 model up
+            self.comm_params += 2 * len(idx) * self.model_size
+            x, y, n, keys = self._stage_cohort(idx)
+            if self.population is not None:
+                # state-table gather: cohort rows with cohort-local ids —
+                # the executor program is byte-identical to the pinned
+                # one, the E-step gather/M-step scatter just act on
+                # (K, d_w) instead of the full (N, d_w). The population
+                # gather drains the async writer first, so last round's
+                # per-shard scatters are visible.
+                rows = jnp.asarray(self.population.gather_local_flat(idx))
+                state = {"local_flat": rows,
+                         "idx": jnp.arange(len(idx), dtype=jnp.int32)}
+            else:
+                state = {"local_flat": self.local_flat,
+                         "idx": jnp.asarray(np.asarray(idx, np.int32))}
+            out = self._round_executor()(self.group_params, state,
+                                         x, y, n, keys)
+            with self.obs.span("fold"):
+                self.group_params = out.group_params
+                with self.obs.span("sync"):
+                    mem = np.asarray(out.membership)
+                    if self.population is not None:
+                        rows = np.asarray(out.assign_state["local_flat"])
+                if self.population is not None:
+                    # async per-shard write-back: overlaps evaluation + the
+                    # next cohort's H2D; the next gather_local_flat drains
+                    # it first
+                    self.population.scatter_local_flat(idx, rows)
+                else:
+                    self.local_flat = out.assign_state["local_flat"]
+                self._adopt_membership(idx, mem)
+                return self._fold_round(t, out, idx)
 
     # -- checkpointing: + the pinned (N, d_w) local-model matrix ------------
     # (population mode keeps the rows host-resident in the state table,

@@ -67,22 +67,21 @@ class IFCATrainer(GroupedTrainer):
         return None      # the in-program argmin-loss stage needs no state
 
     def round(self, t: int, idx=None) -> RoundMetrics:
-        if idx is None:
-            idx = self._select()
-        # IFCA broadcasts ALL m cluster models to every selected client
-        self.comm_params += (self.m + 1) * len(idx) * self.model_size
-        x, y, n = self._client_batch(idx)
-        self.key, sk = jax.random.split(self.key)
-        keys = jax.random.split(sk, len(idx))
-        out = self._round_executor()(self.group_params, None, x, y, n, keys)
-        self.group_params = out.group_params
-        # persists into the population state table when streaming (the
-        # trainer's membership array IS the table's column); migrations
-        # are counted into the telemetry registry on the way through
-        self._adopt_membership(idx, out.membership)
-        acc = self._round_eval(t)
-        self._fold_alive = len(idx)
-        m = RoundMetrics(t, acc, float(out.mean_loss), float(out.discrepancy),
-                         int(out.n_quarantined))
-        self.history.add(m)
-        return m
+        with self.obs.span("round", t=t):
+            if idx is None:
+                idx = self._select()
+            # IFCA broadcasts ALL m cluster models to every selected client
+            self.comm_params += (self.m + 1) * len(idx) * self.model_size
+            x, y, n, keys = self._stage_cohort(idx)
+            out = self._round_executor()(self.group_params, None,
+                                         x, y, n, keys)
+            with self.obs.span("fold"):
+                self.group_params = out.group_params
+                with self.obs.span("sync"):
+                    mem = np.asarray(out.membership)
+                # persists into the population state table when streaming
+                # (the trainer's membership array IS the table's column);
+                # migrations are counted into the telemetry registry on
+                # the way through
+                self._adopt_membership(idx, mem)
+                return self._fold_round(t, out, idx)

@@ -119,93 +119,112 @@ def _make_round_core(model, *, epochs: int, batch_size: int, lr: float,
     loss_one = client_lib.client_mean_loss(model)
 
     def core(group_params, membership, X, Y, n, keys, alive) -> RoundOutput:
+        # each stage runs under a named scope, so a profile attributes the
+        # compiled round's device time to it (op_name metadata; the
+        # program keeps its name)
         state = None
         if assign_fn is not None:
             state = membership
-            membership = assign_fn(group_params, X, Y, n, state)
+            with jax.named_scope("assign"):
+                membership = assign_fn(group_params, X, Y, n, state)
         membership = membership.astype(jnp.int32)
-        # each client trains from ITS group's parameters (one gather, no loop)
-        my_params = jax.tree_util.tree_map(
-            lambda g: g[membership], group_params)
-        deltas, finals = jax.vmap(solve)(my_params, X, Y, n, keys)
+        with jax.named_scope("solver"):
+            # each client trains from ITS group's parameters (one gather,
+            # no loop)
+            my_params = jax.tree_util.tree_map(
+                lambda g: g[membership], group_params)
+            deltas, finals = jax.vmap(solve)(my_params, X, Y, n, keys)
 
         K = membership.shape[0]
         ok = None
         n_quarantined = jnp.int32(0)
         if quarantine:
-            d_sq = sum(jnp.sum(jnp.square(d.reshape(K, -1)), axis=1)
-                       for d in jax.tree_util.tree_leaves(deltas))
-            finite = jnp.isfinite(d_sq)
-            norms = jnp.sqrt(jnp.where(finite, d_sq, 0.0))
-            # median over the alive, finite updates; NaN comparisons are
-            # False, so an all-poisoned cohort quarantines on finiteness
-            # alone rather than on the (undefined) outlier threshold
-            med = jnp.nanmedian(jnp.where((alive > 0) & finite, norms,
-                                          jnp.nan))
-            outlier = norms > quarantine_mult * jnp.maximum(med, 1e-12)
-            ok = finite & ~outlier
-            n_quarantined = jnp.sum((alive > 0) & ~ok).astype(jnp.int32)
-            okb = lambda t: ok.reshape((-1,) + (1,) * (t.ndim - 1))
-            deltas = jax.tree_util.tree_map(
-                lambda d: jnp.where(okb(d), d, 0.0), deltas)
-            finals = jax.tree_util.tree_map(
-                lambda f, p: jnp.where(okb(f), f, p), finals, my_params)
-            alive = alive * ok.astype(alive.dtype)
+            with jax.named_scope("quarantine"):
+                d_sq = sum(jnp.sum(jnp.square(d.reshape(K, -1)), axis=1)
+                           for d in jax.tree_util.tree_leaves(deltas))
+                finite = jnp.isfinite(d_sq)
+                norms = jnp.sqrt(jnp.where(finite, d_sq, 0.0))
+                # median over the alive, finite updates; NaN comparisons
+                # are False, so an all-poisoned cohort quarantines on
+                # finiteness alone rather than on the (undefined) outlier
+                # threshold
+                med = jnp.nanmedian(jnp.where((alive > 0) & finite, norms,
+                                              jnp.nan))
+                outlier = norms > quarantine_mult * jnp.maximum(med, 1e-12)
+                ok = finite & ~outlier
+                n_quarantined = jnp.sum((alive > 0) & ~ok).astype(jnp.int32)
+                okb = lambda t: ok.reshape((-1,) + (1,) * (t.ndim - 1))
+                deltas = jax.tree_util.tree_map(
+                    lambda d: jnp.where(okb(d), d, 0.0), deltas)
+                finals = jax.tree_util.tree_map(
+                    lambda f, p: jnp.where(okb(f), f, p), finals, my_params)
+                alive = alive * ok.astype(alive.dtype)
 
-        # intra-group FedAvg (Alg. 2): segment-sum with n_i weights
-        # normalized within each group
-        onehot = jax.nn.one_hot(membership, m, dtype=jnp.float32)  # (K, m)
-        w = n.astype(jnp.float32) * alive
-        group_tot = onehot.T @ w                                   # (m,)
-        norm_w = w[:, None] * onehot / jnp.maximum(group_tot[None], 1e-9)
+        with jax.named_scope("aggregate"):
+            # intra-group FedAvg (Alg. 2): segment-sum with n_i weights
+            # normalized within each group
+            onehot = jax.nn.one_hot(membership, m, dtype=jnp.float32)  # (K, m)
+            w = n.astype(jnp.float32) * alive
+            group_tot = onehot.T @ w                                   # (m,)
+            norm_w = w[:, None] * onehot / jnp.maximum(group_tot[None],
+                                                       1e-9)
 
-        def agg(d):
-            flat = d.reshape(d.shape[0], -1)                       # (K, p)
-            return (norm_w.T @ flat).reshape((m,) + d.shape[1:])
+            def agg(d):
+                flat = d.reshape(d.shape[0], -1)                       # (K, p)
+                return (norm_w.T @ flat).reshape((m,) + d.shape[1:])
 
-        agg_delta = jax.tree_util.tree_map(agg, deltas)
-        occupied = (group_tot > 0).astype(jnp.float32)
-        tilde = jax.tree_util.tree_map(
-            lambda gp, gd: gp + occupied.reshape(
-                (-1,) + (1,) * (gp.ndim - 1)) * gd,
-            group_params, agg_delta)
+            agg_delta = jax.tree_util.tree_map(agg, deltas)
+            occupied = (group_tot > 0).astype(jnp.float32)
+            tilde = jax.tree_util.tree_map(
+                lambda gp, gd: gp + occupied.reshape(
+                    (-1,) + (1,) * (gp.ndim - 1)) * gd,
+                group_params, agg_delta)
 
-        # mean local training loss of the final local models (what History
-        # reports as mean_loss — one extra forward pass, n_i-weighted)
-        per_client_loss = jax.vmap(loss_one)(finals, X, Y, n)
-        if ok is not None:
-            # a quarantined client's batch may itself be poisoned, so even
-            # the sanitized finals can evaluate to NaN on it
-            per_client_loss = jnp.where(ok, per_client_loss, 0.0)
-        mean_loss = jnp.sum(per_client_loss * w) / jnp.maximum(jnp.sum(w), 1e-9)
+        with jax.named_scope("mean_loss"):
+            # mean local training loss of the final local models (what
+            # History reports as mean_loss — one extra forward pass,
+            # n_i-weighted)
+            per_client_loss = jax.vmap(loss_one)(finals, X, Y, n)
+            if ok is not None:
+                # a quarantined client's batch may itself be poisoned, so
+                # even the sanitized finals can evaluate to NaN on it
+                per_client_loss = jnp.where(ok, per_client_loss, 0.0)
+            mean_loss = jnp.sum(per_client_loss * w) / jnp.maximum(
+                jnp.sum(w), 1e-9)
 
-        # eq. 4 discrepancy: each client vs its group's intra-aggregated model
-        tilde_mine = jax.tree_util.tree_map(lambda t: t[membership], tilde)
-        disc_sq = sum(jnp.sum(jnp.square((f - t).reshape(K, -1)), axis=1)
-                      for f, t in zip(jax.tree_util.tree_leaves(finals),
-                                      jax.tree_util.tree_leaves(tilde_mine)))
-        discrepancy = jnp.sum(jnp.sqrt(disc_sq) * alive) / \
-            jnp.maximum(jnp.sum(alive), 1e-9)
+        with jax.named_scope("discrepancy"):
+            # eq. 4 discrepancy: each client vs its group's intra-aggregated
+            # model
+            tilde_mine = jax.tree_util.tree_map(lambda t: t[membership],
+                                                tilde)
+            disc_sq = sum(
+                jnp.sum(jnp.square((f - t).reshape(K, -1)), axis=1)
+                for f, t in zip(jax.tree_util.tree_leaves(finals),
+                                jax.tree_util.tree_leaves(tilde_mine)))
+            discrepancy = jnp.sum(jnp.sqrt(disc_sq) * alive) / \
+                jnp.maximum(jnp.sum(alive), 1e-9)
 
-        # inter-group aggregation (Alg. 2 lines 17-19), stacked form
-        if eta_g > 0.0 and m > 1:
-            norms = jnp.maximum(_group_norms(tilde, m), 1e-12)
+        with jax.named_scope("aggregate"):
+            # inter-group aggregation (Alg. 2 lines 17-19), stacked form
+            if eta_g > 0.0 and m > 1:
+                norms = jnp.maximum(_group_norms(tilde, m), 1e-12)
 
-            def inter(t):
-                nm = t / norms.reshape((-1,) + (1,) * (t.ndim - 1))
-                return t + eta_g * (jnp.sum(nm, 0, keepdims=True) - nm)
+                def inter(t):
+                    nm = t / norms.reshape((-1,) + (1,) * (t.ndim - 1))
+                    return t + eta_g * (jnp.sum(nm, 0, keepdims=True) - nm)
 
-            new_groups = jax.tree_util.tree_map(inter, tilde)
-        else:
-            new_groups = tilde
+                new_groups = jax.tree_util.tree_map(inter, tilde)
+            else:
+                new_groups = tilde
 
-        global_params = jax.tree_util.tree_map(
-            lambda g: jnp.mean(g, axis=0), new_groups)
-        group_delta_flat = jax.vmap(flatten_updates)(
-            jax.tree_util.tree_map(lambda a, b: a - b,
-                                   new_groups, group_params))
+            global_params = jax.tree_util.tree_map(
+                lambda g: jnp.mean(g, axis=0), new_groups)
+            group_delta_flat = jax.vmap(flatten_updates)(
+                jax.tree_util.tree_map(lambda a, b: a - b,
+                                       new_groups, group_params))
         if assign_fn is not None and state_update_fn is not None:
-            state = state_update_fn(state, membership, deltas, finals)
+            with jax.named_scope("assign"):
+                state = state_update_fn(state, membership, deltas, finals)
         return RoundOutput(new_groups, global_params, agg_delta,
                            group_delta_flat, discrepancy, membership, state,
                            mean_loss, n_quarantined)
@@ -311,7 +330,8 @@ def make_block_executor(model, *, epochs: int, batch_size: int, lr: float,
 
         def step(c, xs):
             ix, ks, al, ev = xs
-            x, y, n = X_all[ix], Y_all[ix], n_all[ix]
+            with jax.named_scope("stage"):
+                x, y, n = X_all[ix], Y_all[ix], n_all[ix]
             trash = c["membership"].shape[0] - 1       # row N: padded lanes
             ix_eff = jnp.where(al > 0, ix, trash).astype(jnp.int32)
             if assign_fn is None:
@@ -405,7 +425,8 @@ def make_async_dispatch_executor(model, *, epochs: int, batch_size: int,
 
     def dispatch_fn(carry, train_stack, idx, keys, alive):
         X_all, Y_all, n_all = train_stack
-        x, y, n = X_all[idx], Y_all[idx], n_all[idx]
+        with jax.named_scope("stage"):
+            x, y, n = X_all[idx], Y_all[idx], n_all[idx]
         trash = carry["membership"].shape[0] - 1
         ix_eff = jnp.where(alive > 0, idx, trash).astype(jnp.int32)
         if assign_fn is None:

@@ -217,23 +217,20 @@ class LCFLTrainer(GroupedTrainer):
         return jnp.asarray(self.membership[idx], jnp.int32)
 
     def round(self, t: int, idx=None) -> RoundMetrics:
-        if idx is None:
-            idx = self._select()
-        self.comm_params += (self.m + 1) * len(idx) * self.model_size
-        x, y, n = self._client_batch(idx)
-        self.key, sk = jax.random.split(self.key)
-        keys = jax.random.split(sk, len(idx))
-        out = self._round_executor()(
-            self.group_params, jnp.asarray(self.membership[idx], jnp.int32),
-            x, y, n, keys)
-        self.group_params = out.group_params
-        self._adopt_membership(idx, out.membership)
-        acc = self._round_eval(t)
-        self._fold_alive = len(idx)
-        m = RoundMetrics(t, acc, float(out.mean_loss), float(out.discrepancy),
-                         int(out.n_quarantined))
-        self.history.add(m)
-        return m
+        with self.obs.span("round", t=t):
+            if idx is None:
+                idx = self._select()
+            self.comm_params += (self.m + 1) * len(idx) * self.model_size
+            x, y, n, keys = self._stage_cohort(idx)
+            out = self._round_executor()(
+                self.group_params,
+                jnp.asarray(self.membership[idx], jnp.int32), x, y, n, keys)
+            with self.obs.span("fold"):
+                self.group_params = out.group_params
+                with self.obs.span("sync"):
+                    mem = np.asarray(out.membership)
+                self._adopt_membership(idx, mem)
+                return self._fold_round(t, out, idx)
 
 
 # ---------------------------------------------------------------------------

@@ -52,7 +52,8 @@ ASYNC_SCHEMA = (
     MetricSpec("async.staleness_hist", HIST, "folds by staleness s"),
 )
 
-#: per-round series counters (engine._emit_round)
+#: per-round series counters (engine._emit_round) and the local solver's
+#: step counts (engine, at every dispatch of the round executor)
 ROUND_SCHEMA = (
     MetricSpec("rounds.completed", COUNTER, "rounds folded into history"),
     MetricSpec("rounds.evals", COUNTER, "rounds with a measured accuracy"),
@@ -64,6 +65,10 @@ ROUND_SCHEMA = (
                "clients probed by the shift detector"),
     MetricSpec("rounds.empty_folds", COUNTER,
                "rounds whose cohort was entirely screened (identity fold)"),
+    MetricSpec("solver.steps_run", COUNTER,
+               "local SGD steps dispatched: lanes x E x ceil(max_n / B)"),
+    MetricSpec("solver.steps_live", COUNTER,
+               "dispatched steps within alive clients' E x ceil(n_i / B)"),
 )
 
 #: coordinator/worker control-plane counters (launch.coordinator) —

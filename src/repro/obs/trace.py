@@ -15,19 +15,26 @@ Per-thread nesting depth is tracked with a ``threading.local`` stack so
 exports can reconstruct parent/child structure (the async window nests
 h2d inside stage inside the dispatch fill loop).
 
+A span opened without a round index ``t`` takes the ``t`` of the span
+it nests in, so helpers that do not know the round (the cohort draw, a
+host read of results) still carry the identifier every span of one round
+shares.
+
 Export targets the Chrome trace-event JSON format (complete events,
 ``ph: "X"``) loadable in ``chrome://tracing`` / Perfetto, validated by
 :func:`validate_chrome_trace`. When ``annotate=True`` each span also
-enters a ``jax.profiler.TraceAnnotation`` so spans line up with XLA
-activity inside a programmatic profiler capture
-(:func:`start_profiler` / :func:`stop_profiler`).
+enters a ``jax.profiler.TraceAnnotation`` named ``repro.<kind>`` (the
+prefix keeps a program span apart from a caller's annotation of the same
+name), so spans line up with XLA activity on the device trace's clock
+inside a profiler capture (:func:`start_profiler` / :func:`stop_profiler`);
+the Chrome export keeps the bare kind.
 
 >>> tr = Tracer(enabled=True)
 >>> with tr.span("stage", t=0):
 ...     with tr.span("h2d"):
 ...         pass
->>> [ (r.kind, r.depth) for r in tr.records() ]
-[('h2d', 1), ('stage', 0)]
+>>> [(r.kind, r.depth, r.attrs["t"]) for r in tr.records()]
+[('h2d', 1, 0), ('stage', 0, 0)]
 >>> Tracer(enabled=False).span("stage") is NULL_SPAN
 True
 """
@@ -54,8 +61,12 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 #: canonical span kinds instrumented across the runtime (docs/observability.md)
-SPAN_KINDS = ("stage", "h2d", "dispatch", "fold", "state-write", "eval",
-              "checkpoint", "lease", "heartbeat")
+SPAN_KINDS = ("round", "select", "cold-start", "stage", "h2d", "dispatch",
+              "sync", "fold", "state-write", "eval", "checkpoint", "lease",
+              "heartbeat")
+
+#: prefix of a span's name in a profiler capture (``annotate=True``)
+ANNOTATION_PREFIX = "repro."
 
 
 class SpanRecord:
@@ -88,10 +99,16 @@ class _Span:
     def __enter__(self):
         tr = self._tracer
         stack = tr._stack()
+        if stack and "t" not in self.attrs:
+            t = stack[-1].attrs.get("t")
+            if t is not None:
+                # a copy: ``wrap`` hands every call the same attrs dict
+                self.attrs = {**self.attrs, "t": t}
         stack.append(self)
         if tr.annotate:
             import jax
-            self._annot = jax.profiler.TraceAnnotation(self.kind)
+            self._annot = jax.profiler.TraceAnnotation(
+                ANNOTATION_PREFIX + self.kind)
             self._annot.__enter__()
         self._start = time.perf_counter_ns()
         return self
