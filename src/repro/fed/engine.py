@@ -4,7 +4,8 @@ Two feeding modes share one compiled round program:
 
   * pinned (default, small N): the padded per-client train/eval stacks are
     uploaded once at init and selection is a device gather — the fast path
-    and the streamed path's equivalence oracle.
+    and the streamed path's equivalence oracle. The train stack is packed
+    client-major, so a cohort is K block copies (docs/scaling.md).
   * ``population=`` (``fed.population.Population``): the population stays
     host-resident in a ``fed.store.ClientStore`` and only the scheduled
     round cohort is streamed to device, double-buffered so the next
@@ -80,11 +81,12 @@ _AsyncLease = leases_lib.Lease
 
 
 @jax.jit
-def gather_cohort(x, y, n, sel):
-    """A cohort's rows of the pinned ``(x, y, n)`` stacks as ONE program,
-    named ``jit_gather_cohort`` in a profile (three eager gathers would be
-    three programs, each behind its own index checks)."""
-    return x[sel], y[sel], n[sel]
+def gather_cohort(stack, sel):
+    """A cohort's rows of the pinned ``ClientStack`` as ONE program, named
+    ``jit_gather_cohort`` in a profile (three eager gathers would be three
+    programs, each behind its own index checks): K block copies
+    (``fed.rounds.gather_clients``)."""
+    return rounds_lib.gather_clients(stack, sel)
 
 
 @dataclass
@@ -276,11 +278,13 @@ class FedAvgTrainer:
             self._train_stack = self._test_stack = None
         else:
             # pin the padded per-client stacks on device once — selection is
-            # a device gather, not a fresh host->device upload every round
+            # a device gather, not a fresh host->device upload every round.
+            # The train stack is packed client-major, so a cohort is K
+            # block copies; the test stack is read whole and stays as is
             self.obs = obs_lib.from_config(cfg)
-            self._train_stack = tuple(jnp.asarray(a) for a in
-                                      (data.x_train, data.y_train,
-                                       data.n_train))
+            self._train_stack = jax.tree_util.tree_map(
+                jnp.asarray, rounds_lib.pack_clients(
+                    data.x_train, data.y_train, data.n_train))
             self._test_stack = tuple(jnp.asarray(a) for a in
                                      (data.x_test, data.y_test, data.n_test))
         self._bind_history(self.history)
@@ -365,7 +369,10 @@ class FedAvgTrainer:
                 self.model, epochs=cfg.local_epochs,
                 batch_size=cfg.batch_size, lr=cfg.lr, mu=cfg.mu,
                 max_samples=self._max_samples, quarantine=cfg.quarantine,
-                quarantine_mult=cfg.quarantine_mult, **self._block_kwargs())
+                quarantine_mult=cfg.quarantine_mult,
+                sharded_stack=parallel_lib.shards_client_axis(
+                    self.mesh, self.n_clients),
+                **self._block_kwargs())
             self._block_exec = self.obs.wrap(
                 "dispatch",
                 parallel_lib.make_sharded_block_executor(fn, self.mesh),
@@ -462,6 +469,7 @@ class FedAvgTrainer:
         do_eval = np.asarray([s[3] for s in staged], bool)
         for s in staged:
             self._count_steps(self._solver_steps(s[0], s[2]))
+            self._count_rows(len(s[0]))
         carry, ys = self._block_executor()(
             self._carry_in(), self._train_stack, self._test_stack,
             idx, keys, alive, jnp.asarray(do_eval))
@@ -504,8 +512,9 @@ class FedAvgTrainer:
             # the live cohort's prefetched device arrays (or a slice of
             # them, e.g. the cold-start subset); store gather otherwise
             return self.population.device_batch(idx)
-        sel = jnp.asarray(np.asarray(idx, np.int32))
-        return gather_cohort(*self._train_stack, sel)
+        sel = np.asarray(idx, np.int32)
+        self._count_rows(len(sel))
+        return gather_cohort(self._train_stack, jnp.asarray(sel))
 
     def _stage_cohort(self, idx):
         """The dispatches that stage a cohort for the per-round executor:
@@ -538,6 +547,12 @@ class FedAvgTrainer:
         run, live = steps
         self.obs.registry.inc("solver.steps_run", run)
         self.obs.registry.inc("solver.steps_live", live)
+
+    def _count_rows(self, clients: int):
+        """Rows a pinned cohort gather copies: every client's padded
+        ``max_n`` rows, from host sizes."""
+        self.obs.registry.inc("stage.rows_gathered",
+                              clients * self._max_samples)
 
     def _fold_round(self, t: int, out, idx) -> RoundMetrics:
         """The rest of the per-round path's fold once the outputs are
@@ -727,7 +742,10 @@ class FedAvgTrainer:
                 self.model, epochs=cfg.local_epochs,
                 batch_size=cfg.batch_size, lr=cfg.lr, mu=cfg.mu,
                 max_samples=self._max_samples, quarantine=cfg.quarantine,
-                quarantine_mult=cfg.quarantine_mult, **self._block_kwargs())
+                quarantine_mult=cfg.quarantine_mult,
+                sharded_stack=parallel_lib.shards_client_axis(
+                    self.mesh, self.n_clients),
+                **self._block_kwargs())
             self._async_exec = self.obs.wrap(
                 "dispatch",
                 parallel_lib.make_async_dispatch_executor(fn, self.mesh),
@@ -857,6 +875,7 @@ class FedAvgTrainer:
             self._count_steps(staged[-1])
             if pinned:
                 idx_d, keys_d, alive_d, _ = staged
+                self._count_rows(len(idx_d))
                 result, mets = exec_(carry, self._train_stack,
                                      idx_d, keys_d, alive_d)
             else:

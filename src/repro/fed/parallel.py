@@ -99,6 +99,20 @@ def mesh_data_shards(mesh) -> int:
     return total
 
 
+def _splits(n: int, total: int) -> bool:
+    """Whether ``total`` data slices split a leading axis of ``n``."""
+    return n > 0 and n % total == 0
+
+
+def shards_client_axis(mesh, n: int) -> bool:
+    """Whether ``shard_client_axis`` spreads a leading (client) axis of
+    ``n`` over more than one device of ``mesh``. A program that reads such
+    a stack by client (``fed.rounds.gather_clients``) picks its gather by
+    this."""
+    total = mesh_data_shards(mesh)
+    return total > 1 and _splits(n, total)
+
+
 def shard_client_axis(mesh, tree):
     """device_put every array leaf with its leading (client) axis sharded
     over the mesh *data* axes when divisible, replicated otherwise.
@@ -120,7 +134,7 @@ def shard_client_axis(mesh, tree):
 
     def put(leaf):
         leaf = jnp.asarray(leaf)
-        if leaf.ndim >= 1 and leaf.shape[0] % total == 0 and leaf.shape[0]:
+        if leaf.ndim >= 1 and _splits(leaf.shape[0], total):
             spec = cohort_pspec(leaf.ndim, data_axes=axes)
         else:
             spec = P(*([None] * leaf.ndim))
@@ -241,7 +255,7 @@ def make_sharded_block_executor(block_fn, mesh=None):
 
     def place_staged(leaf):
         leaf = jnp.asarray(leaf)
-        if leaf.ndim >= 2 and leaf.shape[1] % total == 0 and leaf.shape[1]:
+        if leaf.ndim >= 2 and _splits(leaf.shape[1], total):
             spec = block_staged_pspec(leaf.ndim, data_axes=axes)
         else:
             spec = P(*([None] * leaf.ndim))

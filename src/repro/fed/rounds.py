@@ -52,6 +52,9 @@ estimate-then-loop baselines of the dynamic-assignment frameworks.
 """
 from __future__ import annotations
 
+import dataclasses
+import math
+from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -75,6 +78,87 @@ class RoundOutput(NamedTuple):
                               # of the clients' final local models
     n_quarantined: object = 0  # scalar int32: alive clients whose updates
                                # were screened out this round
+
+
+# 32-bit elements in one (8, 128) TPU tile
+_TILE = 8 * 128
+
+
+@partial(jax.tree_util.register_dataclass, data_fields=["x", "y", "n"],
+         meta_fields=["x_rows", "y_rows"])
+@dataclasses.dataclass(frozen=True)
+class ClientStack:
+    """The pinned per-client train stack, packed client-major.
+
+    ``x`` and ``y`` hold each client's rows flattened and zero-padded to
+    whole (8, 128) tiles: shape ``(N, R // 128, 128)``. The TPU's default
+    layout for that shape keeps the client axis major, each client's block
+    whole tiles of its own, so a cohort is K block copies
+    (``gather_clients``). Unpacked, ``(N, max_n, 784)`` gets a default
+    layout with the client axis minor (``{0,2,1}`` at MNIST's shape), on
+    which every client's copy touches the whole stack. A pinned
+    non-default layout does not hold under JAX 0.9's persistent
+    compilation cache: an array made by a cached program reports the
+    default layout, and the gather is then compiled for that one.
+    ``x_rows`` / ``y_rows`` are one client's unpacked shapes; ``n`` the
+    (N,) client sizes.
+    """
+    x: object
+    y: object
+    n: object
+    x_rows: tuple
+    y_rows: tuple
+
+
+def pack_clients(x, y, n) -> ClientStack:
+    """Host ``(N, max_n, ...)`` / ``(N, max_n)`` / ``(N,)`` stacks ->
+    a host ``ClientStack``."""
+    def pack(a):
+        flat = np.asarray(a).reshape(len(a), -1)
+        pad = -flat.shape[1] % _TILE
+        if pad:
+            flat = np.pad(flat, ((0, 0), (0, pad)))
+        return flat.reshape(len(a), -1, 128)
+
+    return ClientStack(pack(x), pack(y), np.asarray(n),
+                       tuple(np.shape(x)[1:]), tuple(np.shape(y)[1:]))
+
+
+def gather_clients(stack: ClientStack, sel, *, sharded: bool = False):
+    """``(x[sel], y[sel], n[sel])`` of the pinned client stack, unpacked
+    to ``(K, max_n, ...)``, ``(K, max_n)``, ``(K,)``.
+
+    A ``fori_loop`` over ``sel`` copies each selected client's packed
+    block into a ``(K, ...)`` buffer, so the program does not grow with
+    K; the cohort is then unpacked. XLA's gather for ``x[sel]`` passes
+    over the whole stack instead: on a TPU a ``mini-gather-slice`` of
+    every client.
+
+    ``sharded``: the stack's client axis is spread over several devices
+    (``fed.parallel.shards_client_axis``). Such a stack keeps the index
+    gather: XLA partitions it into a masked gather on each shard and one
+    all-reduce of the cohort, where a dynamic slice along the sharded axis
+    would all-gather the whole stack first.
+    """
+    x, y = stack.x, stack.y
+    k = sel.shape[0]
+    if sharded:
+        xs, ys = x[sel], y[sel]
+    else:
+        def copy_client(i, out):
+            return tuple(jax.lax.dynamic_update_index_in_dim(
+                o, jax.lax.dynamic_index_in_dim(a, sel[i], 0,
+                                                keepdims=False), i, 0)
+                for a, o in zip((x, y), out))
+
+        xs, ys = jax.lax.fori_loop(
+            0, k, copy_client, tuple(jnp.zeros((k,) + a.shape[1:], a.dtype)
+                                     for a in (x, y)))
+
+    def unpack(a, rows):
+        return a.reshape(k, -1)[:, :math.prod(rows)].reshape((k,) + rows)
+
+    return unpack(xs, stack.x_rows), unpack(ys, stack.y_rows), stack.n[sel]
 
 
 def stack_trees(trees):
@@ -277,7 +361,8 @@ def make_block_executor(model, *, epochs: int, batch_size: int, lr: float,
                         eta_g: float = 0.0, assign_fn=None,
                         state_update_fn=None, make_state=None,
                         state_to_aux=None, quarantine: bool = False,
-                        quarantine_mult: float = 10.0):
+                        quarantine_mult: float = 10.0,
+                        sharded_stack: bool = False):
     """Returns block_fn(carry, train_stack, test_stack, idx, keys, alive,
     do_eval) -> (carry, (mean_loss, discrepancy, correct, total,
     n_quarantined)) — B fused rounds as ONE ``jax.lax.scan`` dispatch over
@@ -292,12 +377,13 @@ def make_block_executor(model, *, epochs: int, batch_size: int, lr: float,
       ``aux``           framework state (FeSEM: (N+1, d_w) local_flat with
                         the same trash row) or None
 
-    train_stack / test_stack: the pinned ``(x, y, n)`` device stacks —
-    client batches are gathered *in-program* (``X[idx]``), so no per-round
-    H2D. idx: (B, K) int32 staged cohorts; keys: (B, K, 2) uint32; alive:
-    (B, K) float32 zero-weight padding mask (``dropout_rate`` survivors
-    first, padding after — padded lanes aggregate with weight 0 and scatter
-    to the trash row); do_eval: (B,) bool eval-cadence mask
+    train_stack: the pinned ``ClientStack``; test_stack: the pinned
+    ``(x, y, n)`` test stacks — client batches are gathered *in-program*
+    (``gather_clients``; ``sharded_stack`` where the mesh spreads its
+    client axis), so no per-round H2D. idx: (B, K) int32 staged cohorts;
+    keys: (B, K, 2) uint32; alive: (B, K) float32 zero-weight padding mask
+    (``dropout_rate`` survivors first, padding after — padded lanes
+    aggregate with weight 0 and scatter to the trash row); do_eval: (B,) bool eval-cadence mask
     (``FedConfig.eval_every``). Per-round metrics come back stacked (B,):
     mean_loss, discrepancy, the fused grouped-eval correct/total counts
     (0 where do_eval is False) — ints, so the host-side accuracy division
@@ -325,13 +411,13 @@ def make_block_executor(model, *, epochs: int, batch_size: int, lr: float,
     eval_correct = client_lib.grouped_eval_correct(model)
 
     def block_fn(carry, train_stack, test_stack, idx, keys, alive, do_eval):
-        X_all, Y_all, n_all = train_stack
         Xt, Yt, nt = test_stack
 
         def step(c, xs):
             ix, ks, al, ev = xs
             with jax.named_scope("stage"):
-                x, y, n = X_all[ix], Y_all[ix], n_all[ix]
+                x, y, n = gather_clients(train_stack, ix,
+                                         sharded=sharded_stack)
             trash = c["membership"].shape[0] - 1       # row N: padded lanes
             ix_eff = jnp.where(al > 0, ix, trash).astype(jnp.int32)
             if assign_fn is None:
@@ -400,7 +486,8 @@ def make_async_dispatch_executor(model, *, epochs: int, batch_size: int,
                                  assign_fn=None, state_update_fn=None,
                                  make_state=None, state_to_aux=None,
                                  quarantine: bool = False,
-                                 quarantine_mult: float = 10.0):
+                                 quarantine_mult: float = 10.0,
+                                 sharded_stack: bool = False):
     """Returns dispatch_fn(carry, train_stack, idx, keys, alive) ->
     (result_carry, (mean_loss, discrepancy, n_quarantined, membership)) —
     ONE staged round computed against a *snapshot* carry, for the bounded
@@ -424,9 +511,9 @@ def make_async_dispatch_executor(model, *, epochs: int, batch_size: int,
         quarantine=quarantine, quarantine_mult=quarantine_mult)
 
     def dispatch_fn(carry, train_stack, idx, keys, alive):
-        X_all, Y_all, n_all = train_stack
         with jax.named_scope("stage"):
-            x, y, n = X_all[idx], Y_all[idx], n_all[idx]
+            x, y, n = gather_clients(train_stack, idx,
+                                     sharded=sharded_stack)
         trash = carry["membership"].shape[0] - 1
         ix_eff = jnp.where(alive > 0, idx, trash).astype(jnp.int32)
         if assign_fn is None:

@@ -52,8 +52,9 @@ ASYNC_SCHEMA = (
     MetricSpec("async.staleness_hist", HIST, "folds by staleness s"),
 )
 
-#: per-round series counters (engine._emit_round) and the local solver's
-#: step counts (engine, at every dispatch of the round executor)
+#: per-round series counters (engine._emit_round), the local solver's
+#: step counts (engine, at every dispatch of the round executor) and the
+#: rows of every pinned cohort gather
 ROUND_SCHEMA = (
     MetricSpec("rounds.completed", COUNTER, "rounds folded into history"),
     MetricSpec("rounds.evals", COUNTER, "rounds with a measured accuracy"),
@@ -69,6 +70,8 @@ ROUND_SCHEMA = (
                "local SGD steps dispatched: lanes x E x ceil(max_n / B)"),
     MetricSpec("solver.steps_live", COUNTER,
                "dispatched steps within alive clients' E x ceil(n_i / B)"),
+    MetricSpec("stage.rows_gathered", COUNTER,
+               "pinned-stack rows gathered: clients x padded max_n rows"),
 )
 
 #: coordinator/worker control-plane counters (launch.coordinator) —
