@@ -51,7 +51,6 @@ def main(argv=None) -> int:
     from bench import generators as gen
 
     config, traffic = parts["config"], parts["traffic"]
-    fed = config["fed"]
     variants = {"bf16": {"dtype": jnp.bfloat16}, "half": {"drop_half": True},
                 "altered": {"alter_client": 0}, "misassign": {"misassign": True}}
     rows = []
@@ -68,7 +67,7 @@ def main(argv=None) -> int:
         del tr
         gc.collect()
         t1 = time.perf_counter()
-        ref = harness.reference_rounds(data, fed, start, cohorts, prog, ids)
+        ref = harness.reference_rounds(data, config, start, cohorts, prog, ids)
         t2 = time.perf_counter()
         row = {"seed": seed, "program_s": t1 - t0, "reference_s": t2 - t1,
                "program": compare.numbers(start, prog, ref),
@@ -80,7 +79,7 @@ def main(argv=None) -> int:
                    p["membership"], r["membership"]))
                    for p, r in zip(prog, ref)]}
         for name, kw in variants.items():
-            alt = harness.reference_rounds(data, fed, start, cohorts, prog,
+            alt = harness.reference_rounds(data, config, start, cohorts, prog,
                                            ids, follow=False, **kw)
             for a, p in zip(alt, prog):
                 a["n_test"] = p["n_test"]
@@ -89,7 +88,7 @@ def main(argv=None) -> int:
                        for a, r in zip(alt, ref))
             row[name] = compare.numbers(start, alt, ref if same else
                                         harness.reference_rounds(
-                                            data, fed, start, cohorts, alt,
+                                            data, config, start, cohorts, alt,
                                             ids))
         print(json.dumps(row), flush=True)
         rows.append(row)

@@ -1,16 +1,20 @@
 """The numbers that decide ``correct``: the program's check rounds against
 the reference's.
 
-A check round's reading is a dict with the group models ``groups``
-(m-stacked), the auxiliary global model ``glob``, the round's mean local
-loss ``loss`` and its correct test predictions ``correct``. ``start`` is
-the state both sides started from. The numbers:
+A check round's reading is a dict with the group models ``groups`` (an
+m-stacked parameter pytree), the auxiliary global model ``glob``, the
+round's mean local loss ``loss`` and its correct test predictions
+``correct``. ``start`` is the state both sides started from. The
+numbers:
 
   loss_gap    max over the check rounds of |loss - ref| / |ref|
   update_gap  the first round's change of every leaf (each group's and the
               global model's), by its norm: the worst leaf's
               |norm - ref norm| / max(ref norm, median ref norm)
   change_gap  the same for the change over all the check rounds
+  assign_gap  max over the check rounds' newcomers of how far the eq.-9
+              dissimilarity of the group the program chose lies above the
+              least (``Reference.cold_assign``)
   acc_gap     max over the check rounds that evaluated of
               |correct - ref| / the test samples evaluated
 
@@ -20,25 +24,29 @@ left out of update_gap and change_gap.
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
-
-from bench.reference import LEAVES
 
 NEGLIGIBLE = 1e-3
 
 
+def _changes(after, before):
+    """(path, change) of every leaf of a parameter pytree, in float64."""
+    leaves = jax.tree_util.tree_flatten_with_path(after)[0]
+    for (path, a), b in zip(leaves, jax.tree_util.tree_leaves(before)):
+        yield (jax.tree_util.keystr(path),
+               np.asarray(a, np.float64) - np.asarray(b, np.float64))
+
+
 def leaf_norms(after: dict, before: dict) -> dict:
-    """Norm of every leaf's change, per group and for the global model."""
+    """Norm of every leaf's change, per group and for the global model,
+    named by the leaf's path in the parameter pytree."""
     out = {}
-    m = next(iter(after["groups"].values())).shape[0]
-    for k in LEAVES:
-        for j in range(m):
-            d = (np.asarray(after["groups"][k][j], np.float64)
-                 - np.asarray(before["groups"][k][j], np.float64))
-            out[f"group{j}.{k}"] = float(np.linalg.norm(d))
-        d = (np.asarray(after["glob"][k], np.float64)
-             - np.asarray(before["glob"][k], np.float64))
-        out[f"global.{k}"] = float(np.linalg.norm(d))
+    for key, d in _changes(after["groups"], before["groups"]):
+        for j in range(d.shape[0]):
+            out[f"group{j}{key}"] = float(np.linalg.norm(d[j]))
+    for key, d in _changes(after["glob"], before["glob"]):
+        out[f"global{key}"] = float(np.linalg.norm(d))
     return out
 
 
@@ -74,9 +82,10 @@ def numbers(start: dict, prog: list, ref: list) -> dict:
 
 def verdict(nums: dict, limits: dict) -> tuple:
     """-> (correct, checks): every limited number within its limit, and
-    each number beside its limit. A limit of None marks a number that is
-    shown and does not decide."""
-    checks = {k: {"value": v, "limit": limits[k]} for k, v in nums.items()}
+    each number beside its limit. A number whose limit is None, or that
+    has no limit, is shown and does not decide."""
+    checks = {k: {"value": v, "limit": limits.get(k)}
+              for k, v in nums.items()}
     ok = all(np.isfinite(v) for v in nums.values()) and all(
         c["value"] <= c["limit"] for c in checks.values()
         if c["limit"] is not None)

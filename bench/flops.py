@@ -1,4 +1,7 @@
-"""Operation counts from shapes, for the utilization metrics.
+"""Operation counts from shapes, for the utilization metrics. A model's
+operations per sample come from its kind module
+(``bench/models/<kind>.py:train_flops_per_sample``); this module counts
+the samples.
 
 Only the multiply-adds of the matrix products are counted (2 operations
 each); bias adds, activations, the softmax and the optimizer update are
@@ -8,18 +11,6 @@ never high.
 from __future__ import annotations
 
 import numpy as np
-
-
-def mlp_train_flops_per_sample(in_dim: int, hidden: int,
-                               n_classes: int) -> int:
-    """Forward and backward operations of one sample through a
-    one-hidden-layer MLP: the forward pass's two products, and in the
-    backward pass the two weight gradients and the gradient into the
-    hidden layer (none flows into the input)."""
-    first, second = in_dim * hidden, hidden * n_classes
-    forward = 2 * (first + second)
-    backward = 2 * first + 2 * 2 * second
-    return forward + backward
 
 
 def live_sgd_flops(n_train, *, epochs: int, batch_size: int,

@@ -1,4 +1,6 @@
-"""The benchmark's own generators of client data.
+"""The benchmark's own generators of client data: ``make_data`` and the
+helpers the generators share. Each generator is a module of its own,
+``bench/data/<generator>.py``, named by ``config["data"]["generator"]``.
 
 Everything here is a pure function of a seed and the parameter dict of
 ``bench/configs/<config>.json``, so the same seed gives the same inputs on
@@ -12,11 +14,17 @@ client sizes) and with the paper's client counts and sample totals.
 Client sizes are power-law draws clipped to ``[min_size, max_size]`` and
 then scaled so that they sum to the configured total exactly.
 
-Each data generator returns a dict of padded numpy arrays:
-``x_train (N, max_train, dim)``, ``y_train (N, max_train)``,
-``n_train (N,)`` and the same for ``test``, plus ``n_classes``.
+A generator's ``make(seed, p)`` returns a dict of padded numpy arrays:
+``x_train (N, max_train, *row)``, ``y_train (N, max_train, *labels)``,
+``n_train (N,)`` and the same for ``test``, plus ``n_classes``. Its
+``virtual(seed, p, n_clients)``, where it streams, returns the size
+tables ``n_train``, ``n_test``, the padded widths ``max_train``,
+``max_test``, ``n_classes`` and ``client_fn(i)`` -> client ``i``'s
+unpadded ``x, y, x_test, y_test``.
 """
 from __future__ import annotations
+
+import importlib
 
 import numpy as np
 
@@ -81,99 +89,12 @@ def _pack(xs, ys, sizes, test_share: float, n_classes: int) -> dict:
     return out
 
 
-def femnist_like(seed: int, p: dict) -> dict:
-    """Writer-level non-IID clients: each writer belongs to one of
-    ``n_styles`` latent styles (a shared near-identity linear mix and
-    shift of the class prototypes), covers ``writer_classes`` (a range,
-    both ends included) of the classes, and adds its own small noise."""
-    rng = np.random.default_rng([seed, 0xFE41])
-    dim, C = p["dim"], p["n_classes"]
-    protos = _prototypes(rng, C, dim)
-    styles = []
-    for _ in range(p["n_styles"]):
-        M = np.eye(dim, dtype=np.float32) + np.float32(
-            0.35 / np.sqrt(dim)) * rng.standard_normal((dim, dim),
-                                                       dtype=np.float32)
-        b = np.float32(0.9) * rng.standard_normal(dim, dtype=np.float32)
-        styles.append((M, b))
-    sizes = sizes_with_total(rng, p["n_clients"], p["total_samples"],
-                             p["min_size"], p["max_size"], p["size_alpha"])
-    style_of = rng.integers(0, p["n_styles"], p["n_clients"])
-    c_lo, c_hi = p["writer_classes"]
-    xs, ys = [], []
-    for i, n_i in enumerate(sizes):
-        M, b = styles[style_of[i]]
-        cls = rng.choice(C, rng.integers(c_lo, c_hi + 1), replace=False)
-        y = rng.choice(cls, n_i).astype(np.int32)
-        x = protos[y] + np.float32(0.9) * rng.standard_normal(
-            (n_i, dim), dtype=np.float32)
-        x = x @ M.T + b + np.float32(0.1) * rng.standard_normal(
-            (n_i, dim), dtype=np.float32)
-        xs.append(x)
-        ys.append(y)
-    return _pack(xs, ys, sizes, p["test_share"], C)
-
-
-def mnist_like(seed: int, p: dict) -> dict:
-    """Label-skewed class-cluster clients: each client holds
-    ``classes_per_client`` classes in near-equal shares; a sample is its
-    class prototype plus unit Gaussian noise."""
-    rng = np.random.default_rng([seed, 0x3417])
-    dim, C, k = p["dim"], p["n_classes"], p["classes_per_client"]
-    protos = _prototypes(rng, C, dim)
-    sizes = sizes_with_total(rng, p["n_clients"], p["total_samples"],
-                             p["min_size"], p["max_size"], p["size_alpha"])
-    xs, ys = [], []
-    for n_i in sizes:
-        cls = rng.choice(C, k, replace=False)
-        y = np.repeat(cls, [n_i // k + (j < n_i % k) for j in range(k)])
-        y = rng.permutation(y).astype(np.int32)
-        xs.append(protos[y] + rng.standard_normal((n_i, dim),
-                                                  dtype=np.float32))
-        ys.append(y)
-    return _pack(xs, ys, sizes, p["test_share"], C)
-
-
-def virtual_mnist_like(seed: int, p: dict, n_clients: int) -> dict:
-    """``mnist_like`` clients for a population of ``n_clients``, made one
-    at a time on first touch: the size table (the configuration's sizes
-    scaled to ``n_clients`` at the same mean) is all that exists up front,
-    and client ``i``'s samples come from its own seed ``[seed, tag, i]``.
-    -> the size table, padded widths and ``client_fn(i)`` returning the
-    unpadded ``x, y, x_test, y_test`` a ``VirtualClientStore`` takes."""
-    rng = np.random.default_rng([seed, 0x3417])
-    dim, C, k = p["dim"], p["n_classes"], p["classes_per_client"]
-    protos = _prototypes(rng, C, dim)
-    total = round(p["total_samples"] * n_clients / p["n_clients"])
-    sizes = sizes_with_total(rng, n_clients, total, p["min_size"],
-                             p["max_size"], p["size_alpha"])
-    n_test = np.maximum(1, (sizes * p["test_share"]).astype(np.int64))
-    n_train = sizes - n_test
-
-    def client_fn(i: int) -> dict:
-        r = np.random.default_rng([seed, 0x7C11, int(i)])
-        n_i, te = int(sizes[i]), int(n_test[i])
-        cls = r.choice(C, k, replace=False)
-        y = np.repeat(cls, [n_i // k + (j < n_i % k) for j in range(k)])
-        y = r.permutation(y).astype(np.int32)
-        x = protos[y] + r.standard_normal((n_i, dim), dtype=np.float32)
-        return {"x": x[te:], "y": y[te:], "x_test": x[:te], "y_test": y[:te]}
-
-    return {"n_train": n_train.astype(np.int32),
-            "n_test": n_test.astype(np.int32), "n_classes": int(C),
-            "max_train": int(n_train.max()), "max_test": int(n_test.max()),
-            "client_fn": client_fn}
-
-
-DATA = {"femnist_like": femnist_like, "mnist_like": mnist_like}
-VIRTUAL = {"mnist_like": virtual_mnist_like}
-
-
 def make_data(seed: int, config: dict, traffic: dict) -> dict:
     """The client data of a configuration under a traffic mix: padded
     arrays for pinned feeding, a lazy population of the traffic's size
     for streamed feeding."""
     p = config["data"]
+    gen = importlib.import_module(f"bench.data.{p['generator']}")
     if traffic["feeding"] == "population":
-        return VIRTUAL[p["generator"]](seed, p, int(traffic["population"]))
-    return DATA[p["generator"]](seed, p)
+        return gen.virtual(seed, p, int(traffic["population"]))
+    return gen.make(seed, p)

@@ -3,8 +3,10 @@
 A cell of ``BENCHMARK.json`` names a configuration (``bench/configs/``), a
 traffic mix (``bench/traffic/``) and its chips; its limits for ``correct``
 are in ``bench/limits/<cell>.json`` and each per-layer metric is read by
-``bench/metrics/<metric>.py``. Nothing here names a cell, a configuration
-or a metric.
+``bench/metrics/<metric>.py``. A configuration's model is its kind module
+(``bench/models/<kind>.py``) and its data its generator
+(``bench/data/<generator>.py``). Nothing here names a cell, a
+configuration, a model or a metric.
 
 A run:
 
@@ -36,6 +38,8 @@ import tempfile
 import time
 
 import numpy as np
+
+from bench import models
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # JAX's persistent compilation cache, at a fixed path inside the checkout
@@ -146,18 +150,19 @@ def memory_peak() -> int | None:
 # the system under test and its feeding
 # ---------------------------------------------------------------------------
 def build_trainer(config: dict, traffic: dict, seed: int, data: dict):
-    """FedGroupTrainer at the configuration's FedGroup settings and the
-    traffic's cohort size and eval cadence, over pinned client data or a
-    streamed ``Population`` (``traffic["feeding"]``). The cold start
-    pre-trains ``cold_start_share`` of the clients active at the start."""
+    """FedGroupTrainer of the configuration's model at its FedGroup
+    settings and the traffic's cohort size and eval cadence, over pinned
+    client data or a streamed ``Population`` (``traffic["feeding"]``).
+    The cold start pre-trains ``cold_start_share`` of the clients active
+    at the start."""
     from repro.core.fedgroup import FedGroupTrainer
     from repro.data.federated import FederatedData
     from repro.fed.engine import FedConfig
-    from repro.models.paper_models import mlp
 
     m, f = config["model"], config["fed"]
-    if m["kind"] != "mlp" or f["framework"] != "fedgroup":
+    if f["framework"] != "fedgroup":
         raise ValueError(f"unsupported configuration {config['name']!r}")
+    kind = models.load(config)
     fd = pop = None
     if traffic["feeding"] == "pinned":
         fd = FederatedData(config["name"], data["x_train"], data["y_train"],
@@ -170,7 +175,8 @@ def build_trainer(config: dict, traffic: dict, seed: int, data: dict):
         store = VirtualClientStore(
             config["name"], len(data["n_train"]), data["client_fn"],
             max_train=data["max_train"], max_test=data["max_test"],
-            feat=(m["in_dim"],), n_classes=data["n_classes"],
+            feat=kind.row_shape(m, config["data"]),
+            n_classes=data["n_classes"],
             n_train=data["n_train"], n_test=data["n_test"])
         pop = Population(store, PopulationConfig(
             initial_active=traffic["initial_active"],
@@ -188,8 +194,8 @@ def build_trainer(config: dict, traffic: dict, seed: int, data: dict):
                     n_groups=f["n_groups"], pretrain_scale=alpha,
                     eta_g=f["eta_g"], measure=f["measure"],
                     eval_every=traffic["eval_every"])
-    tr = FedGroupTrainer(mlp(m["in_dim"], m["hidden"], m["n_classes"]), fd,
-                         cfg, population=pop)
+    tr = FedGroupTrainer(kind.program(m, config["data"]), fd, cfg,
+                         population=pop)
     if tr.model_size != m["d_w"]:
         raise ValueError(f"model has d_w={tr.model_size}, the configuration "
                          f"states {m['d_w']}")
@@ -238,8 +244,9 @@ class Feed:
         return self.tr.round(t), self.last
 
 
-def _host(tree) -> dict:
-    return {k: np.array(v) for k, v in tree.items()}
+def _host(tree):
+    import jax
+    return jax.tree_util.tree_map(np.array, tree)
 
 
 def state_of(tr) -> dict:
@@ -277,14 +284,14 @@ def check_rounds(tr, feed: Feed, data: dict, n: int):
     return start, readings, cohorts
 
 
-def reference_rounds(data, fed: dict, start: dict, cohorts, prog: list,
+def reference_rounds(data, config: dict, start: dict, cohorts, prog: list,
                      ids, follow: bool = True, **kw) -> list:
     """The reference's readings over the same cohorts from ``start``; it
     evaluates in the rounds the program evaluated, over the same clients,
     and its newcomers join the groups the program chose (``follow``)."""
     from bench.reference import Reference
     import jax.numpy as jnp
-    ref = Reference(data, fed, **kw)
+    ref = Reference(data, config["fed"], models.load(config).apply, **kw)
     state = dict(start, key=jnp.asarray(start["key"]))
     out = []
     for idx, p in zip(cohorts, prog):
@@ -344,9 +351,9 @@ def _window(tr, feed: Feed, data: dict, t0: int, seconds: float,
     import jax
     from bench import flops as flops_lib
 
-    m, f = config["model"], config["fed"]
-    per_sample = flops_lib.mlp_train_flops_per_sample(
-        m["in_dim"], m["hidden"], m["n_classes"])
+    f = config["fed"]
+    per_sample = models.load(config).train_flops_per_sample(
+        config["model"], config["data"])
     lat, live, failed, evals = [], 0, 0, 0
     t = t0
     feed.annotate = annotate
@@ -466,7 +473,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     result["metrics"] = metrics
     result["device"] = device
 
-    ref = reference_rounds(data, config["fed"], start, cohorts, prog, ids)
+    ref = reference_rounds(data, config, start, cohorts, prog, ids)
     nums = compare.numbers(start, prog, ref)
     ok, checks = compare.verdict(nums, parts["limits"])
     result["correct"] = ok and failed == 0

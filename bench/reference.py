@@ -11,10 +11,13 @@ SGD step splits that key and draws a batch of ``B`` rows uniformly, with
 replacement, from the client's ``n_i`` samples; a client takes
 ``E * ceil(n_i / B)`` steps.
 
-Parameters are dicts ``{"w1", "b1", "w2", "b2"}`` of a one-hidden-layer
-ReLU MLP; a group state is the same dict with a leading group axis.
-``dtype`` sets the precision the reference computes in: float32 is the
-reference, bfloat16 is the lower-precision control.
+The forward pass ``apply(p, x)`` is the configuration's kind module's
+(``bench/models/<kind>.py``); everything here is generic over its
+parameter pytree, walked in ``jax.tree_util`` order (the order the
+program flattens an update in), and over labels of any trailing shape:
+one a row, or one a token. A group state is the same pytree with a
+leading group axis. ``dtype`` sets the precision the reference computes
+in: float32 is the reference, bfloat16 is the lower-precision control.
 """
 from __future__ import annotations
 
@@ -24,26 +27,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-LEAVES = ("b1", "b2", "w1", "w2")     # sorted: the order a flat update uses
-
-
-def apply(p, x):
-    h = jax.nn.relu(x @ p["w1"] + p["b1"])
-    return h @ p["w2"] + p["b2"]
-
-
-def batch_loss(p, x, y):
+def batch_loss(apply, p, x, y):
     logits = apply(p, x)
     logp = jax.nn.log_softmax(logits, -1)
-    return -jnp.mean(jnp.take_along_axis(logp, y[:, None], -1))
+    return -jnp.mean(jnp.take_along_axis(logp, y[..., None], -1))
 
 
-@partial(jax.jit, static_argnames=("batch_size", "epochs", "lr"))
-def local_sgd(p0, x, y, n, key, *, batch_size: int, epochs: int, lr: float):
+@partial(jax.jit, static_argnames=("apply", "batch_size", "epochs", "lr"))
+def local_sgd(p0, x, y, n, key, *, apply, batch_size: int, epochs: int,
+              lr: float):
     """E epochs of minibatch SGD on one client -> final parameters."""
     n = jnp.maximum(n, 1)
     steps = epochs * ((n + batch_size - 1) // batch_size)
-    grad = jax.grad(batch_loss)
+    grad = jax.grad(partial(batch_loss, apply))
 
     def body(_, carry):
         p, key = carry
@@ -57,35 +53,41 @@ def local_sgd(p0, x, y, n, key, *, batch_size: int, epochs: int, lr: float):
     return p
 
 
-@jax.jit
-def client_loss(p, x, y, n):
-    """Mean cross-entropy of ``p`` over a client's ``n`` valid rows."""
+@partial(jax.jit, static_argnames=("apply",))
+def client_loss(p, x, y, n, *, apply):
+    """Mean cross-entropy of ``p`` over a client's ``n`` valid rows (a
+    row's own loss is the mean over its labels)."""
     with jax.default_matmul_precision("highest"):
         logp = jax.nn.log_softmax(apply(p, x).astype(jnp.float32), -1)
-    ce = -jnp.take_along_axis(logp, y[:, None], -1)[:, 0]
+    ce = -jnp.take_along_axis(logp, y[..., None], -1)[..., 0]
+    if y.ndim > 1:
+        ce = jnp.mean(ce.reshape(y.shape[0], -1), -1)
     return jnp.sum(jnp.where(jnp.arange(y.shape[0]) < n, ce, 0.0)) / n
 
 
-@jax.jit
-def client_correct(p, x, y, n):
+@partial(jax.jit, static_argnames=("apply",))
+def client_correct(p, x, y, n, *, apply):
     """Correct predictions of ``p`` over a client's ``n`` valid rows."""
     with jax.default_matmul_precision("highest"):
         pred = jnp.argmax(apply(p, x), -1)
-    return jnp.sum((pred == y) & (jnp.arange(y.shape[0]) < n))
+    valid = jnp.arange(y.shape[0]) < n
+    return jnp.sum((pred == y)
+                   & valid.reshape(valid.shape + (1,) * (y.ndim - 1)))
 
 
 def flat(p) -> np.ndarray:
-    """A parameter dict as one float32 vector, leaves in ``LEAVES`` order."""
-    return np.concatenate([np.asarray(p[k], np.float32).ravel()
-                           for k in LEAVES])
+    """A parameter pytree as one float32 vector, leaves in
+    ``jax.tree_util`` order."""
+    return np.concatenate([np.asarray(v, np.float32).ravel()
+                           for v in jax.tree_util.tree_leaves(p)])
 
 
-def group(gp, j: int) -> dict:
-    return {k: v[j] for k, v in gp.items()}
+def group(gp, j: int):
+    return jax.tree_util.tree_map(lambda v: v[j], gp)
 
 
 def _cast(tree, dtype):
-    return {k: jnp.asarray(v, dtype) for k, v in tree.items()}
+    return jax.tree_util.tree_map(lambda v: jnp.asarray(v, dtype), tree)
 
 
 class Reference:
@@ -93,17 +95,18 @@ class Reference:
 
     ``data`` is the benchmark's data dict (``bench.generators``): padded
     arrays, or for a streamed population a ``client_fn``; ``fed`` the
-    configuration's ``fed`` block. ``drop_half`` and ``alter_client``
+    configuration's ``fed`` block, ``apply`` its kind's forward pass.
+    ``drop_half`` and ``alter_client``
     plant faults (the reference put in the program's place, for
     calibration): the second half of every cohort left out, and the train
     labels of the cohort's client ``alter_client`` shifted by one class,
     so that its update answers the wrong question; ``misassign`` sends a
     round's first newcomer to its most dissimilar group."""
 
-    def __init__(self, data: dict, fed: dict, dtype=jnp.float32,
+    def __init__(self, data: dict, fed: dict, apply, dtype=jnp.float32,
                  drop_half: bool = False, alter_client: int | None = None,
                  misassign: bool = False):
-        self.d, self.fed, self.dtype = data, fed, dtype
+        self.d, self.fed, self.apply, self.dtype = data, fed, apply, dtype
         self.drop_half, self.alter_client = drop_half, alter_client
         self.misassign = misassign
 
@@ -111,12 +114,12 @@ class Reference:
         d = self.d
         if "client_fn" in d:
             c = d["client_fn"](i)
-            key = "x" if split == "train" else "x_test"
+            xk, yk = ("x", "y") if split == "train" else ("x_test", "y_test")
             rows = d[f"max_{split}"]
-            x = np.zeros((rows,) + c[key].shape[1:], np.float32)
-            y = np.zeros(rows, np.int32)
-            x[:len(c[key])] = c[key]
-            y[:len(c[key])] = c["y" if split == "train" else "y_test"]
+            x = np.zeros((rows,) + c[xk].shape[1:], np.float32)
+            y = np.zeros((rows,) + c[yk].shape[1:], np.int32)
+            x[:len(c[xk])] = c[xk]
+            y[:len(c[yk])] = c[yk]
             x = jnp.asarray(x, self.dtype)
         else:
             x = jnp.asarray(d[f"x_{split}"][i], self.dtype)
@@ -127,7 +130,7 @@ class Reference:
 
     def _solve(self, p, i, key, epochs, alter: bool = False):
         x, y, n = self._client(i, alter=alter)
-        return local_sgd(_cast(p, self.dtype), x, y, n, key,
+        return local_sgd(_cast(p, self.dtype), x, y, n, key, apply=self.apply,
                          batch_size=int(self.fed["batch_size"]),
                          epochs=int(epochs), lr=float(self.fed["lr"]))
 
@@ -158,7 +161,7 @@ class Reference:
         return np.asarray(groups, np.int64), gaps, key
 
     def round(self, state: dict, idx, eval_ids=None, follow=None) -> dict:
-        """One round from ``state`` = {"groups" (m-stacked dict),
+        """One round from ``state`` = {"groups" (m-stacked pytree),
         "glob", "group_dir" (m, d_w), "membership" (N,), "key"} -> the
         next state plus this round's "loss", its newcomers' "assign_gaps"
         and, where ``eval_ids`` is given, "correct": the test predictions
@@ -178,8 +181,10 @@ class Reference:
         keys = jax.random.split(sk, len(idx))
         if self.drop_half:
             idx, keys = idx[:len(idx) // 2], keys[:len(idx) // 2]
-        gp = {k: np.asarray(v, np.float64) for k, v in state["groups"].items()}
-        num = {k: np.zeros_like(v) for k, v in gp.items()}
+        tree = jax.tree_util
+        gp = tree.tree_map(lambda v: np.asarray(v, np.float64),
+                           state["groups"])
+        num = tree.tree_map(np.zeros_like, gp)
         wsum = np.zeros(m)
         loss_num = 0.0
         for c, (i, k) in enumerate(zip(idx, keys)):
@@ -189,19 +194,20 @@ class Reference:
             final = self._solve(start, int(i), k, self.fed["local_epochs"],
                                 alter)
             x, y, n = self._client(int(i), alter=alter)
-            loss_num += n * float(client_loss(final, x, y, n))
-            for name in LEAVES:
-                num[name][g] += n * (np.asarray(final[name], np.float64)
-                                     - np.asarray(start[name], np.float64))
+            loss_num += n * float(client_loss(final, x, y, n,
+                                              apply=self.apply))
+            for acc, f, s0 in zip(tree.tree_leaves(num),
+                                  tree.tree_leaves(final),
+                                  tree.tree_leaves(start)):
+                acc[g] += n * (np.asarray(f, np.float64)
+                               - np.asarray(s0, np.float64))
             wsum[g] += n
-        occupied = wsum > 0
-        new = {}
-        for name in LEAVES:
-            scale = (1.0 / np.where(occupied, wsum, 1.0)).reshape(
-                (m,) + (1,) * (gp[name].ndim - 1))
-            new[name] = gp[name] + num[name] * scale
-        groups = {k: v.astype(np.float32) for k, v in new.items()}
-        glob = {k: v.mean(0).astype(np.float32) for k, v in new.items()}
+        scale = 1.0 / np.where(wsum > 0, wsum, 1.0)
+        new = tree.tree_map(
+            lambda v, u: v + u * scale.reshape((m,) + (1,) * (v.ndim - 1)),
+            gp, num)
+        groups = tree.tree_map(lambda v: v.astype(np.float32), new)
+        glob = tree.tree_map(lambda v: v.mean(0).astype(np.float32), new)
         group_dir = np.stack([flat(group(new, j)) - flat(group(gp, j))
                               for j in range(m)])
         return {"groups": groups, "glob": glob, "group_dir": group_dir,
@@ -219,5 +225,6 @@ class Reference:
         total = 0
         for i in np.asarray(ids)[mem[np.asarray(ids)] >= 0]:
             x, y, n = self._client(int(i), "test")
-            total += int(client_correct(models[int(mem[i])], x, y, n))
+            total += int(client_correct(models[int(mem[i])], x, y, n,
+                                        apply=self.apply))
         return total

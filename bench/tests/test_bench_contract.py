@@ -1,6 +1,7 @@
 """``BENCHMARK.json`` and every file it names: names, units, the metric
 each per-layer metric moves, the share of four-chip cells, the files the
-harness finds by name, and ``bench/run.py`` refusing to run off a chip.
+harness finds by name (traffic, limits, model kind, data generator,
+metric readers), and ``bench/run.py`` refusing to run off a chip.
 Nothing here loads the TPU library."""
 import importlib
 import json
@@ -83,6 +84,22 @@ def test_configs_and_cells(spec):
                                                f"{name}.json"))
         assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"]
     assert {c["config"] for c in spec["workloads"]} == configs
+    for w in spec["workloads"]:
+        # the model kind and the generator the harness finds by name
+        cfg_file = next(c["file"] for c in spec["configs"]
+                        if c["name"] == w["config"])
+        with open(os.path.join(ROOT, cfg_file)) as f:
+            cfg = json.load(f)
+        with open(os.path.join(ROOT, "bench", "traffic",
+                               f"{w['traffic']}.json")) as f:
+            traffic = json.load(f)
+        kind = importlib.import_module(f"bench.models.{cfg['model']['kind']}")
+        for fn in ("program", "row_shape", "apply",
+                   "train_flops_per_sample"):
+            assert callable(getattr(kind, fn)), (w["name"], fn)
+        gen = importlib.import_module(f"bench.data.{cfg['data']['generator']}")
+        streamed = traffic["feeding"] == "population"
+        assert callable(getattr(gen, "virtual" if streamed else "make"))
     four = sum(w["chips"] == 4 for w in spec["workloads"])
     assert four <= max(1, len(spec["workloads"]) // 2)
 

@@ -4,6 +4,7 @@ controls that the comparison has to refuse."""
 import os
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -41,8 +42,8 @@ def test_reference_agrees_with_the_program(share, streamed):
     start, prog, cohorts, ids = _program(parts, data)
     newcomers = sum(int((start["membership"][c] < 0).sum()) for c in cohorts)
     assert (newcomers > 0) == (share < 1.0 or streamed)
-    ref = harness.reference_rounds(data, parts["config"]["fed"], start,
-                                   cohorts, prog, ids)
+    ref = harness.reference_rounds(data, parts["config"], start, cohorts,
+                                   prog, ids)
     nums = compare.numbers(start, prog, ref)
     # eq. 9 placed every newcomer in the reference's least dissimilar group
     assert ("assign_gap" in nums) == (newcomers > 0)
@@ -60,12 +61,12 @@ def _bf16(a):
                                      "program_on_bf16_data_and_weights"])
 def test_lower_precision_fails(control):
     parts = tiny_cell.parts()
-    fed = parts["config"]["fed"]
-    data = gen.make_data(SEED, parts["config"], parts["traffic"])
+    config = parts["config"]
+    data = gen.make_data(SEED, config, parts["traffic"])
     if control == "reference_in_bf16":
         start, prog, cohorts, ids = _program(parts, data)
-        low = harness.reference_rounds(data, fed, start, cohorts, prog, ids,
-                                       dtype=jnp.bfloat16)
+        low = harness.reference_rounds(data, config, start, cohorts, prog,
+                                       ids, dtype=jnp.bfloat16)
         for a, p in zip(low, prog):
             a["n_test"] = p["n_test"]
         prog = low
@@ -76,14 +77,22 @@ def test_lower_precision_fails(control):
                                    low)
         feed = harness.Feed(tr)
         tr.group_cold_start()
-        tr.group_params = {k: jnp.asarray(_bf16(v))
-                           for k, v in tr.group_params.items()}
+        tr.group_params = jax.tree_util.tree_map(
+            lambda v: jnp.asarray(_bf16(v)), tr.group_params)
         # the reference starts from the same rounded weights and keeps
         # the data at full precision
         start, prog, cohorts = harness.check_rounds(tr, feed, low, 3)
         ids = harness.eval_ids(tr)
         tr.close()
-    ref = harness.reference_rounds(data, fed, start, cohorts, prog, ids)
+    ref = harness.reference_rounds(data, config, start, cohorts, prog, ids)
     ok, checks = compare.verdict(compare.numbers(start, prog, ref),
                                  parts["limits"])
     assert not ok, checks
+
+
+def test_a_number_with_no_limit_is_shown_and_does_not_decide():
+    nums = {"loss_gap": 0.001, "acc_gap": 0.5}
+    ok, checks = compare.verdict(nums, {"loss_gap": 0.01})
+    assert ok and checks["acc_gap"] == {"value": 0.5, "limit": None}
+    ok, checks = compare.verdict(nums, {"loss_gap": 0.0001})
+    assert not ok and checks["loss_gap"]["limit"] == 0.0001
